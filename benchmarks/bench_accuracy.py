@@ -77,7 +77,7 @@ def run(n_rounds: int = 30, n_selected: int = 128, full: bool = False,
 
 
 def rows_from_results(res) -> list:
-    """Per-round rows (machine-readable) from a run()/cached result dict."""
+    """Per-round rows (machine-readable) from a run() result dict."""
     modes = list(res)
     n = len(res[modes[0]]["accs"])
     rows = []
@@ -90,45 +90,28 @@ def rows_from_results(res) -> list:
     return rows
 
 
-def main(argv=None, cached: str = "results/fig2c.json"):
-    """Prints the stored 30-round N=128 experiment when present (a full
-    recompute is ~45 CPU-min; regenerate with bench_accuracy.run()).
-    Any non-default strategy/rounds/fault knob forces a fresh run."""
+def main(argv=None):
+    """Runs the Fig. 2c comparison (12 rounds unless ``--rounds``) and
+    prints the per-round accuracy/involvement table."""
     import argparse
-    import json
-    import os
 
     ap = argparse.ArgumentParser()
-    ap.add_argument("--rounds", type=int, default=None,
-                    help="recompute with this many rounds (default: cached)")
+    ap.add_argument("--rounds", type=int, default=12)
     ap.add_argument("--n-selected", type=int, default=128)
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     fl.add_experiment_cli_args(ap)
     args = ap.parse_args(argv)
 
-    from repro.pon import PonConfig, pon_config_from_args
+    from repro.pon import pon_config_from_args
     t0 = time.time()
-    strategy = fl.canonical_name(args.strategy)
-    # the cache only represents the stock experiment — ANY knob off its
-    # default (strategy, rounds, fault injection, N, seed, PON transport)
-    # must force a fresh run instead of printing stale numbers
-    defaults = (args.rounds is None and strategy == "sfl_two_step"
-                and args.overselect == 0.0 and args.p_crash == 0.0
-                and args.p_transient == 0.0 and not args.full
-                and args.n_selected == 128 and args.seed == 0
-                and pon_config_from_args(args) == PonConfig())
-    if defaults and os.path.exists(cached):
-        print(f"# cached run from {cached} (30 rounds, N=128)")
-        res = json.load(open(cached))
-    else:
-        res = run(n_rounds=args.rounds if args.rounds is not None else 12,
-                  n_selected=args.n_selected, full=args.full, seed=args.seed,
-                  modes=fl.comparison_modes(strategy),
-                  pon=pon_config_from_args(args),
-                  overselect=args.overselect, p_crash=args.p_crash,
-                  p_transient=args.p_transient,
-                  strategy_kwargs=fl.strategy_kwargs_from_args(args))
+    res = run(n_rounds=args.rounds, n_selected=args.n_selected,
+              full=args.full, seed=args.seed,
+              modes=fl.comparison_modes(fl.canonical_name(args.strategy)),
+              pon=pon_config_from_args(args),
+              overselect=args.overselect, p_crash=args.p_crash,
+              p_transient=args.p_transient,
+              strategy_kwargs=fl.strategy_kwargs_from_args(args))
     modes = list(res)
     print("bench_accuracy (Fig 2c)")
     print("round," + ",".join(f"{m}_acc" for m in modes)

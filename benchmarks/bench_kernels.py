@@ -1,6 +1,9 @@
-"""Kernel micro-bench: us_per_call for the ONU aggregation + quantize ops
-(jnp reference path on CPU; Pallas interpret timings are not meaningful),
+"""Kernel micro-bench: us_per_call for the ONU aggregation + quantize ops,
 plus derived wire-bytes — one row per transport variant.
+
+Every row stamps the dispatch mode that ran (``compiled`` Pallas on a TPU,
+the jnp ``ref`` path elsewhere; see ``kernels.ops.dispatch_mode``) and the
+device, so a CPU reference timing can never be read as a kernel time.
 
 Per-rep wall times are recorded into the ambient ``repro.obs`` metrics
 registry (histograms ``kernels.<name>.us``) so a ``--metrics-out`` session
@@ -68,9 +71,14 @@ def main():
                  lambda a, b, c: ops.agg_reduce_quant(a, b, c, kf), x, w, m)
     rows.append({"name": "agg_reduce_quant_onu20x6.6M", "us_per_call": f_us,
                  "derived": "fused_agg+int8"})
+    dev = jax.devices()[0]
+    for r in rows:
+        r["dispatch"] = ops.dispatch_mode()
+        r["device"] = f"{dev.platform}:{dev.device_kind}"
     return report.emit_rows(
         rows, "kernels",
-        [("name", ""), ("us_per_call", ".0f"), ("derived", "")],
+        [("name", ""), ("us_per_call", ".0f"), ("derived", ""),
+         ("dispatch", ""), ("device", "")],
         header="bench_kernels")
 
 

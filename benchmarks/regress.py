@@ -14,6 +14,10 @@ natural axis — N, (dba, wavelengths, bg_load), (n_pons, mode), round,
   * **timing** (``us_per_call``, ``wall_s``, ``*_s`` budgets measured on
     the host) — WARN-only; CI machines are noisy and host time is not a
     simulator property.
+  * **incomparable** — both rows stamp the kernel dispatch mode or device
+    and the stamps differ (a TPU ``compiled`` row against a CPU ``ref``
+    row): one finding per differing stamp, and the row's timings are not
+    compared. A row without the stamp (an older artifact) is compared.
 
 Exit code 0 = clean (warnings allowed), 1 = hard regressions — the CI
 gate (.github/workflows) runs this at smoke settings against the
@@ -46,6 +50,8 @@ ALIGN_KEYS: Dict[str, Tuple[str, ...]] = {
 }
 
 _SKIP_FIELDS = {"bench", "bench_schema", "obs_schema"}
+# what ran a timed row (kernel rows): timings differ in kind across them
+_STAMP_FIELDS = ("dispatch", "device")
 # host-measured time: never a hard failure
 _TIMING_PAT = re.compile(r"(us_per_call|wall_s|^t_to_target_s$|compile_s)")
 _ACC_PAT = re.compile(r"acc")
@@ -61,11 +67,12 @@ class Finding:
         self.metric = metric
         self.base = base
         self.cand = cand
-        self.status = status            # "fail" | "warn" | "missing"
+        self.status = status  # "fail" | "warn" | "missing" | "incomparable"
         self.note = note
 
     def line(self) -> str:
-        tag = {"fail": "FAIL", "warn": "warn", "missing": "MISS"}[self.status]
+        tag = {"fail": "FAIL", "warn": "warn", "missing": "MISS",
+               "incomparable": "INCOMPARABLE"}[self.status]
         s = (f"[{tag}] {self.bench}{self.key}.{self.metric}: "
              f"{self.base!r} -> {self.cand!r}")
         if self.note:
@@ -140,9 +147,18 @@ def compare(baseline: Dict[str, List[Dict]], candidate: Dict[str, List[Dict]],
                                         "present", "missing",
                                         "row absent from baseline"))
                 continue
+            stamps = [m for m in _STAMP_FIELDS if m in row_b and m in row_c
+                      and row_b[m] != row_c[m]]
+            for metric in stamps:
+                findings.append(Finding(bench, f"{key}", metric, row_b[metric],
+                                        row_c[metric], "incomparable",
+                                        "timings not compared"))
             for metric in sorted(set(row_b) | set(row_c)):
-                if metric in _SKIP_FIELDS or metric in ALIGN_KEYS.get(
-                        bench, ()):
+                if (metric in _SKIP_FIELDS or metric in _STAMP_FIELDS
+                        or metric in ALIGN_KEYS.get(bench, ())):
+                    continue
+                if stamps and (metric == "derived"
+                               or _TIMING_PAT.search(metric)):
                     continue
                 vb, vc = row_b.get(metric), row_c.get(metric)
                 status = classify(metric, vb, vc, rtol, acc_drop)
@@ -169,7 +185,8 @@ def _render_html(findings: List[Finding], baseline: str,
             "<th>metric</th><th>baseline</th><th>candidate</th>"
             "<th>note</th></tr>"]
     for f in findings:
-        cls = {"fail": "diff", "warn": "warn", "missing": "missing_a"}
+        cls = {"fail": "diff", "warn": "warn", "missing": "missing_a",
+               "incomparable": "warn"}
         rows.append(f'<tr class="{cls[f.status]}"><td>{f.status}</td>'
                     f"<td>{_h.escape(f.bench)}</td>"
                     f"<td>{_h.escape(str(f.key))}</td>"
@@ -228,12 +245,14 @@ def main(argv: Optional[List[str]] = None) -> int:
     findings = compare(baseline, candidate, rtol=args.rtol,
                        acc_drop=args.acc_drop, benches=benches)
     n_fail = sum(1 for f in findings if f.status in ("fail", "missing"))
-    n_warn = len(findings) - n_fail
+    n_incomparable = sum(1 for f in findings if f.status == "incomparable")
+    n_warn = len(findings) - n_fail - n_incomparable
     print(f"baseline:  {base_path}")
     print(f"candidate: {args.candidate}")
     for f in findings:
         print(f.line())
-    print(f"TOTAL: {n_fail} hard regressions, {n_warn} warnings")
+    print(f"TOTAL: {n_fail} hard regressions, {n_warn} warnings, "
+          f"{n_incomparable} incomparable")
     if args.html:
         with open(args.html, "w") as f:
             f.write(_render_html(findings, base_path, args.candidate))

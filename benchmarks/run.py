@@ -20,7 +20,7 @@
 ``--json OUT.json`` additionally writes every bench's rows as
 machine-readable JSON ({bench: [row, ...]}) so the perf/accuracy
 trajectory is trackable across PRs; ``--rounds R`` overrides the accuracy
-bench's round count (forces a fresh run instead of the cached figure).
+bench's round count.
 """
 from __future__ import annotations
 
@@ -37,7 +37,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true",
                     help="accuracy bench with the full LEAF CNN (slow)")
     ap.add_argument("--rounds", type=int, default=None,
-                    help="accuracy bench rounds (forces recompute)")
+                    help="accuracy bench rounds")
     ap.add_argument("--json", default=None, metavar="OUT.json",
                     help="write per-bench rows as JSON")
     ap.add_argument("--trace-out", default=None, metavar="TRACE.json",
@@ -49,6 +49,8 @@ def main() -> None:
                          "sweep (per-driver registries folded at exit)")
     args = ap.parse_args()
 
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro import obs
     sess = obs.session(trace_out=args.trace_out,
                        metrics_out=args.metrics_out, driver="bench_sweep")

@@ -126,17 +126,33 @@ def sharding_tree(mesh: Mesh, rules: ShardingRules, logical_tree) -> Any:
     )
 
 
+def param_sharding_tree(mesh: Mesh, rules: ShardingRules, params, logical) -> Any:
+    """A NamedSharding per parameter leaf from its logical axes; a mesh axis
+    that does not divide its dim is dropped (see ``filter_valid_spec``).
+    ``params`` may hold arrays or ShapeDtypeStructs."""
+    return jax.tree.map(
+        lambda x, lg: NamedSharding(
+            mesh, filter_valid_spec(mesh, logical_to_physical(rules, lg), x.shape)),
+        params, logical,
+        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+
+
 def shard_or_replicate(mesh: Mesh, x, spec: P):
     """Device-put with a named sharding (used by hosts feeding real runs)."""
     return jax.device_put(x, NamedSharding(mesh, spec))
 
 
 def constrain(x, rules: ShardingRules, logical: Sequence[Optional[str]]):
-    """with_sharding_constraint by logical names; no-op outside jit/mesh."""
-    try:
-        return jax.lax.with_sharding_constraint(x, logical_to_physical(rules, logical))
-    except (ValueError, RuntimeError):
+    """with_sharding_constraint by logical names.
+
+    A no-op exactly when no mesh is set (``jax.set_mesh``). Under a mesh an
+    all-``None`` spec is still applied: it pins the value replicated, which
+    is what makes the classical regime's gradient sync a flat all-reduce.
+    Anything the constraint raises (an axis the mesh lacks, a bad spec)
+    propagates."""
+    if jax.sharding.get_abstract_mesh().empty:
         return x
+    return jax.lax.with_sharding_constraint(x, logical_to_physical(rules, logical))
 
 
 def filter_valid_spec(mesh: Mesh, spec: P, shape: Tuple[int, ...]) -> P:

@@ -225,6 +225,9 @@ class GradientBackend:
                  n_clients: Optional[int] = None):
         # model/data imports are lazy so `import repro.fl` stays light for
         # the client-stacked path
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from repro.common.sharding import param_sharding_tree
         from repro.launch import specs as S
         from repro.models import transformer
         from repro.optim import make_optimizer
@@ -241,12 +244,28 @@ class GradientBackend:
                               else rng.integers(50, 400, n).astype(np.float32))
         self.onu_ids = (onu_ids if onu_ids is not None
                         else np.zeros(len(self.sample_counts), np.int64))
-        self.params, _ = transformer.init_params(model_cfg,
-                                                 jax.random.PRNGKey(seed))
+        params, logical = transformer.init_params(model_cfg,
+                                                  jax.random.PRNGKey(seed))
+        # place every leaf by its logical axes (FSDP rules shard it over
+        # the mesh, replicated rules copy it to every device): left
+        # unplaced, the step keeps sharded only what its constraints pin
+        # (the embedding) and replicates the rest of the model
+        shard = param_sharding_tree(mesh, rules, params, logical)
+        self.params = jax.device_put(params, shard)
         self.opt = make_optimizer(opt_name)
-        self.opt_state = self.opt.init(self.params)
-        self.train_step = jax.jit(S.make_train_step(
-            model_cfg, rules, opt_name, lr, microbatches, seed=seed))
+        replicated = NamedSharding(mesh, P())
+        opt_state = self.opt.init(self.params)
+        opt_shard = jax.tree.map(
+            lambda x: (x.sharding if isinstance(x.sharding, NamedSharding)
+                       else replicated), opt_state)
+        self.opt_state = jax.device_put(opt_state, opt_shard)
+        # the step hands its state back as it was placed: left to choose,
+        # the compiler spells the same layouts differently (a size-1 axis
+        # dropped), and the second step misses the jit cache and recompiles
+        self.train_step = jax.jit(
+            S.make_train_step(model_cfg, rules, opt_name, lr, microbatches,
+                              seed=seed),
+            out_shardings=(shard, opt_shard, replicated))
 
     def run_round(self, rnd: int, selected: np.ndarray, mask: np.ndarray,
                   rt: Dict[str, Any], rng: np.random.Generator

@@ -12,10 +12,11 @@ axis resident (C is small: ≤ clients-per-ONU), accumulating in f32.
 
 ``agg_reduce_quant`` fuses the compression PR's int8/int4 quantization into
 the same pass: the per-block absmax needed for the quantization scale is
-computed while the aggregate is still VMEM-resident (pass A emits aggregate
-+ block absmaxes together), so the θ tile is never re-read from HBM just to
-find its dynamic range; pass B is the standard stochastic-rounding quantize
-(kernels/quantize.py) at the reduced max(absmax)/qmax scale.
+computed while the aggregate is still VMEM-resident (pass A emits the
+aggregate and a running max|θ| together), so the θ tile is never re-read
+from HBM just to find its dynamic range; pass B is the standard
+stochastic-rounding quantize (kernels/quantize.py) at the max|θ|/qmax
+scale.
 
 Zero-length inputs (C=0 when every client of an ONU crashed, N=0 for an
 empty parameter group) return exact zeros / identity scale early — an empty
@@ -27,9 +28,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.quantize import _make_quant_kernel, _qmax
+from repro.kernels.quantize import SCALAR_SPEC, _make_quant_kernel, _qmax
 
 BLOCK_N = 2048  # f32 VMEM tile: C×2048×4B ≤ ~0.5 MB for C ≤ 64
+_AMAX_TILE = (8, 128)  # the running max|θ|, one f32 VMEM tile
 
 
 def _agg_kernel(x_ref, w_ref, out_ref):
@@ -40,13 +42,21 @@ def _agg_kernel(x_ref, w_ref, out_ref):
 
 
 def _agg_absmax_kernel(x_ref, w_ref, out_ref, amax_ref):
-    # same reduction, but also emit this block's max|Σ| while it is still
-    # in VMEM — the fusion that saves the extra HBM pass before quantizing
+    # same reduction, but also fold this block's max|Σ| into a running max
+    # while it is still in VMEM — the fusion that saves the extra HBM pass
+    # before quantizing. amax_ref is one lane-aligned (8, 128) tile mapped
+    # to the same block at every grid step, so it stays resident and is
+    # written back once (the grid runs sequentially).
     x = x_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     s = jnp.sum(x * w, axis=0)
     out_ref[...] = s
-    amax_ref[0] = jnp.max(jnp.abs(s))
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        amax_ref[...] = jnp.zeros(amax_ref.shape, jnp.float32)
+
+    amax_ref[...] = jnp.maximum(amax_ref[...], jnp.max(jnp.abs(s)))
 
 
 def _padded(x, N: int, block_n: int):
@@ -108,15 +118,15 @@ def agg_reduce_quant(x, weights, mask, key, *, bits: int = 8,
         ],
         out_specs=[
             pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((1,), lambda i: (i,)),
+            pl.BlockSpec(_AMAX_TILE, lambda i: (0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((npad,), jnp.float32),
-            jax.ShapeDtypeStruct((grid[0],), jnp.float32),
+            jax.ShapeDtypeStruct(_AMAX_TILE, jnp.float32),
         ],
         interpret=interpret,
     )(x, w)
-    scale = jnp.maximum(jnp.max(amax), 1e-12) / qmax
+    scale = jnp.maximum(amax[0, 0], 1e-12) / qmax
     # pass B: the standard quantize kernel over the padded aggregate
     # (padding quantizes to 0 and is sliced off)
     noise = jax.random.uniform(key, (N,), jnp.float32)
@@ -128,7 +138,7 @@ def agg_reduce_quant(x, weights, mask, key, *, bits: int = 8,
         in_specs=[
             pl.BlockSpec((bn,), lambda i: (i,)),
             pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            SCALAR_SPEC,
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((npad,), jnp.int8),

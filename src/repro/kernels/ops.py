@@ -1,11 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels with backend dispatch.
 
-On TPU the Pallas kernels run compiled (interpret=False); on CPU (this
-container) they execute in interpret mode when explicitly requested (tests)
-and otherwise fall back to the jnp reference — which is also what the
-GSPMD dry-run lowers, since Mosaic kernels cannot lower for the CPU
-backend. The dispatch is a single choke point so a real TPU deployment
-flips one flag.
+On TPU the Pallas kernels run compiled (interpret=False); on a CPU host
+they execute in interpret mode when explicitly requested (tests) and
+otherwise fall back to the jnp reference — which is also what the GSPMD
+dry-run lowers, since Mosaic kernels cannot lower for the CPU backend.
+:func:`dispatch_mode` is the single choke point, and what a benchmark row
+stamps so that a reference timing is never read as a kernel time.
 """
 from __future__ import annotations
 
@@ -31,8 +31,9 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _mode(use_pallas: Optional[bool]) -> str:
-    """'compiled' | 'interpret' | 'ref'."""
+def dispatch_mode(use_pallas: Optional[bool] = None) -> str:
+    """'compiled' | 'interpret' | 'ref' — what a call with ``use_pallas``
+    runs on the default backend."""
     if use_pallas is None:
         return "compiled" if _on_tpu() else "ref"
     if use_pallas:
@@ -48,7 +49,7 @@ def _mode(use_pallas: Optional[bool]) -> str:
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def agg_reduce(x, weights, mask, use_pallas: Optional[bool] = None):
     with named_scope("kernels.agg_reduce"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.agg_reduce_ref(x, weights, mask)
         return _agg_pallas(x, weights, mask, interpret=(m == "interpret"))
@@ -57,7 +58,7 @@ def agg_reduce(x, weights, mask, use_pallas: Optional[bool] = None):
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def quantize_int8(x, key, use_pallas: Optional[bool] = None):
     with named_scope("kernels.quantize_int8"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.quantize_int8_ref(x, key)
         return _quant_pallas(x, key, interpret=(m == "interpret"))
@@ -66,7 +67,7 @@ def quantize_int8(x, key, use_pallas: Optional[bool] = None):
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def dequantize_int8(q, scale, use_pallas: Optional[bool] = None):
     with named_scope("kernels.dequantize_int8"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.dequantize_int8_ref(q, scale)
         return _dequant_pallas(q, scale, interpret=(m == "interpret"))
@@ -75,7 +76,7 @@ def dequantize_int8(q, scale, use_pallas: Optional[bool] = None):
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def quantize_int4(x, key, use_pallas: Optional[bool] = None):
     with named_scope("kernels.quantize_int4"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.quantize_int4_ref(x, key)
         return _quant4_pallas(x, key, interpret=(m == "interpret"))
@@ -89,7 +90,7 @@ dequantize_int4 = dequantize_int8
 @functools.partial(jax.jit, static_argnames=("k", "use_pallas"))
 def topk_sparsify(x, k: int, use_pallas: Optional[bool] = None):
     with named_scope("kernels.topk_sparsify"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.topk_sparsify_ref(x, k)
         return _topk_pallas(x, k, interpret=(m == "interpret"))
@@ -99,7 +100,7 @@ def topk_sparsify(x, k: int, use_pallas: Optional[bool] = None):
 def agg_reduce_quant(x, weights, mask, key, bits: int = 8,
                      use_pallas: Optional[bool] = None):
     with named_scope("kernels.agg_reduce_quant"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.agg_reduce_quant_ref(x, weights, mask, key, bits)
         return _agg_quant_pallas(x, weights, mask, key, bits=bits,
@@ -110,7 +111,7 @@ def agg_reduce_quant(x, weights, mask, key, bits: int = 8,
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     use_pallas: Optional[bool] = None):
     with named_scope("kernels.flash_attention"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.attention_ref(q, k, v, causal=causal, window=window)
         return _flash_pallas(q, k, v, causal=causal, window=window,
@@ -120,7 +121,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def rglru_scan(a, b, h0=None, use_pallas: Optional[bool] = None):
     with named_scope("kernels.rglru_scan"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.rglru_scan_ref(a, b, h0)
         return _rglru_pallas(a, b, h0, interpret=(m == "interpret"))
@@ -129,7 +130,7 @@ def rglru_scan(a, b, h0=None, use_pallas: Optional[bool] = None):
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def rwkv6_scan(r, k, v, logw, u, use_pallas: Optional[bool] = None):
     with named_scope("kernels.rwkv6_scan"):
-        m = _mode(use_pallas)
+        m = dispatch_mode(use_pallas)
         if m == "ref":
             return ref.rwkv6_ref(r, k, v, logw, u)
         return _rwkv_pallas(r, k, v, logw, u, interpret=(m == "interpret"))

@@ -25,8 +25,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK = 8192
+
+# A scalar operand (scale, threshold) lives in SMEM: the chip's compiler
+# only loads from VMEM/SMEM refs, never from an unplaced (ANY) one.
+SCALAR_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _qmax(bits: int) -> float:
@@ -76,7 +81,7 @@ def quantize_intb(x, key, bits: int, *, block: int = BLOCK,
         in_specs=[
             pl.BlockSpec((bn,), lambda i: (i,)),
             pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            SCALAR_SPEC,
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((npad,), jnp.int8),
@@ -103,7 +108,7 @@ def dequantize_int8(q, scale, *, block: int = BLOCK, interpret: bool = False):
         grid=(npad // bn,),
         in_specs=[
             pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            SCALAR_SPEC,
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((npad,), jnp.float32),
@@ -146,7 +151,7 @@ def topk_mask(x, thresh, *, block: int = BLOCK, interpret: bool = False):
         grid=(npad // bn,),
         in_specs=[
             pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec(memory_space=pl.ANY),
+            SCALAR_SPEC,
         ],
         out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((npad,), jnp.float32),

@@ -140,14 +140,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, mode: str,
            "overrides": list(sets or []),
            "params": cfg.param_count, "active_params": cfg.active_param_count}
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         fn, args, _ = S.input_specs(cfg, shp, mesh, rules, opt_name, micro,
                                     transport=transport)
         lowered = jax.jit(fn).lower(*args)
         compiled = lowered.compile()
-    from repro.common.compat import cost_analysis
     ma = compiled.memory_analysis()
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["compile_s"] = round(time.time() - t0, 2)
     rec["memory"] = {
         "argument_gb": ma.argument_size_in_bytes / 1e9,

@@ -201,8 +201,7 @@ class SegmentCost:
 
 
 def cost_of_compiled(compiled, mesh, txt_override: Optional[str] = None) -> SegmentCost:
-    from repro.common.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     txt = txt_override if txt_override is not None else compiled.as_text()
     coll = parse_collectives(txt, mesh)
     ma = compiled.memory_analysis()

@@ -247,7 +247,7 @@ def cell_cost(cfg: ModelConfig, shp: ShapeConfig, mesh: Mesh,
         unit_rules = rules.with_(batch=("data",))
         unit_shp = dataclasses.replace(
             shp, global_batch=max(1, shp.global_batch // n_pod))
-    with mesh:
+    with jax.set_mesh(mesh):
         c_unit = lower_unit_segment(cfg, unit_shp, mesh, unit_rules)
         c_model1 = lower_model1_segment(cfg, shp, mesh, rules, opt_name,
                                         transport=transport)

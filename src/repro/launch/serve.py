@@ -54,7 +54,7 @@ def main():
         jax.random.PRNGKey(args.seed), 4)
     cache_len = args.prompt_len + args.gen
 
-    with mesh:
+    with jax.set_mesh(mesh):
         params, _ = transformer.init_params(cfg, k_init)
         B, P = args.batch, args.prompt_len
         if cfg.frontend == "frames":

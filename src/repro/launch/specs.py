@@ -20,7 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.common.sharding import ShardingRules, filter_valid_spec, logical_to_physical
+from repro.common.sharding import (ShardingRules, filter_valid_spec, logical_to_physical,
+                                   param_sharding_tree)
 from repro.models import transformer
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.optim import make_optimizer
@@ -56,11 +57,7 @@ def batch_struct(cfg: ModelConfig, shp: ShapeConfig, mesh: Mesh,
 def param_shardings(cfg: ModelConfig, mesh: Mesh, rules: ShardingRules):
     params, logical = transformer.init_params(cfg, abstract=True,
                                               tp=mesh.shape.get("model", 1))
-    shard = jax.tree.map(
-        lambda x, lg: NamedSharding(
-            mesh, filter_valid_spec(mesh, logical_to_physical(rules, lg), x.shape)),
-        params, logical,
-        is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+    shard = param_sharding_tree(mesh, rules, params, logical)
     abstract = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
         params, shard,
@@ -273,8 +270,7 @@ def make_train_step(cfg: ModelConfig, rules: ShardingRules, opt_name: str = "ada
             bspecs = jax.tree.map(lambda _: P("pod"), batch)
             pspecs = jax.tree.map(lambda _: P(), params)
             ospecs = jax.tree.map(lambda _: P(), opt_state)
-            from repro.common.compat import shard_map
-            fn = shard_map(
+            fn = jax.shard_map(
                 pod_body, mesh=mesh,
                 in_specs=(pspecs, ospecs, bspecs, P()),
                 out_specs=(pspecs, ospecs, P()),

@@ -26,6 +26,7 @@ import numpy as np
 
 from repro import configs, fl, obs
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.sharding import ShardingRules
 from repro.launch.mesh import make_test_mesh
 from repro.obs import obs_logging
@@ -43,6 +44,26 @@ def build_rules(mesh, transport: str) -> ShardingRules:
                           tensor="model" if "model" in axes else None,
                           expert="model" if "model" in axes else None)
     return rules.replicated() if transport == "classical" else rules
+
+
+def make_backend(cfg, exp: fl.ExperimentConfig, *, opt: str = "adamw",
+                 lr: float = 3e-4, batch: int = 8, seq: int = 128,
+                 micro: int = 1, seed: int = 0) -> fl.GradientBackend:
+    """The gradient-regime backend over every device: a ``("data",
+    "model")`` mesh of all devices on ``data``, with the sharding rules the
+    strategy's transport induces (FSDP for sfl/hier, replicated for
+    classical). Drive it under ``jax.set_mesh(backend.mesh)``."""
+    strategy = exp.make_strategy()
+    mesh = make_test_mesh((len(jax.devices()), 1), ("data", "model"))
+    rules = build_rules(mesh, strategy.transport)
+    rng = np.random.default_rng(seed)
+    flc = exp.fl
+    onu_ids = np.arange(flc.n_clients) // flc.clients_per_onu
+    sample_counts = rng.integers(50, 400, flc.n_clients).astype(np.float32)
+    return fl.GradientBackend(
+        cfg, strategy, mesh, rules, opt_name=opt, lr=lr, batch=batch,
+        seq=seq, microbatches=micro, seed=seed, sample_counts=sample_counts,
+        onu_ids=onu_ids)
 
 
 def main():
@@ -69,6 +90,7 @@ def main():
     fl.add_experiment_cli_args(ap)
     obs_logging.add_logging_cli_args(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     logger = obs_logging.logger_from_args(args)
     sess = obs.session_from_args(
@@ -78,22 +100,11 @@ def main():
     exp = fl.experiment_config_from_args(args, n_rounds=args.steps)
     # one selected client per batch row: client_weight aligns with the batch
     exp = exp.with_fl(n_selected=args.batch)
-    strategy = exp.make_strategy()
+    backend = make_backend(cfg, exp, opt=args.opt, lr=args.lr,
+                           batch=args.batch, seq=args.seq, micro=args.micro,
+                           seed=args.seed)
 
-    n_dev = len(jax.devices())
-    mesh = make_test_mesh((n_dev, 1), ("data", "model"))
-    rules = build_rules(mesh, strategy.transport)
-
-    rng = np.random.default_rng(args.seed)
-    flc = exp.fl
-    onu_ids = np.arange(flc.n_clients) // flc.clients_per_onu
-    sample_counts = rng.integers(50, 400, flc.n_clients).astype(np.float32)
-
-    with mesh:
-        backend = fl.GradientBackend(
-            cfg, strategy, mesh, rules, opt_name=args.opt, lr=args.lr,
-            batch=args.batch, seq=args.seq, microbatches=args.micro,
-            seed=args.seed, sample_counts=sample_counts, onu_ids=onu_ids)
+    with jax.set_mesh(backend.mesh):
         step0 = 0
         if args.ckpt:
             last = latest_step(args.ckpt)

@@ -134,12 +134,8 @@ def _combine_manual(yd_flat, dst, wts, EC: int, rules: ShardingRules):
     from jax.sharding import PartitionSpec as P
 
     axis = rules.axis_for("experts")
-    try:  # the `with mesh:` context (dry-run/train drivers)
-        from jax._src.mesh import thread_resources
-        phys = thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover
-        phys = None
-    if phys is None or phys.empty or axis not in phys.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()  # launch.*'s jax.set_mesh
+    if mesh.empty or axis not in mesh.axis_names:
         return None  # caller falls back to the gather path
 
     def body(yd_local, dst_l, w_l):
@@ -155,10 +151,9 @@ def _combine_manual(yd_flat, dst, wts, EC: int, rules: ShardingRules):
             out = out + g * (w_l[..., kk] * valid[..., kk].astype(w_l.dtype))[..., None]
         return jax.lax.psum(out, axis)
 
-    from repro.common.compat import shard_map
-    return shard_map(
+    return jax.shard_map(
         body,
-        mesh=phys,
+        mesh=mesh,
         in_specs=(P(None, axis, None), P(), P()),
         out_specs=P(),
         axis_names={axis},

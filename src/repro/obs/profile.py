@@ -1,12 +1,10 @@
-"""JAX profiler shims + wall-time capture for the compute side.
+"""JAX profiler annotations + wall-time capture for the compute side.
 
 Two complementary mechanisms:
 
   * :func:`annotate` — a ``jax.profiler.TraceAnnotation`` context (named
     interval in a captured XLA profile) for *host-side* regions: backend
-    train/eval calls, kernel dispatch in the benchmarks. Degrades to a
-    no-op when the profiler API is unavailable, so library code can wrap
-    unconditionally.
+    train/eval calls, kernel dispatch in the benchmarks.
   * :func:`named_scope` — ``jax.named_scope`` for *traced* code: inside a
     ``jit`` the annotation attaches to the emitted HLO ops, which is how
     ``agg_reduce``/``quantize`` show up as named regions in device
@@ -21,53 +19,33 @@ compile vs steady-state split simply time the first call separately
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Callable, Tuple
 
-try:
-    import jax
-    _TraceAnnotation = getattr(jax.profiler, "TraceAnnotation", None)
-    _named_scope = getattr(jax, "named_scope", None)
-except Exception:                                   # pragma: no cover
-    jax = None
-    _TraceAnnotation = None
-    _named_scope = None
-
-_NULL = contextlib.nullcontext()
+import jax
 
 
 def annotate(name: str):
-    """Host-side profiler annotation (no-op without jax.profiler)."""
-    if _TraceAnnotation is None:
-        return _NULL
-    return _TraceAnnotation(name)
+    """Host-side profiler annotation."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 def named_scope(name: str):
-    """Trace-time scope: names the HLO emitted under it (no-op shim)."""
-    if _named_scope is None:
-        return _NULL
-    return _named_scope(name)
-
-
-def _block(x: Any) -> Any:
-    if jax is not None:
-        try:
-            return jax.block_until_ready(x)
-        except Exception:
-            pass
-    return x
+    """Trace-time scope: names the HLO emitted under it."""
+    return jax.named_scope(name)
 
 
 def timed(name: str, fn: Callable, *args,
           metrics=None, tracer=None, **kwargs) -> Tuple[Any, float]:
     """Run ``fn(*args, **kwargs)`` under a profiler annotation, blocking on
     the result; returns ``(result, wall_seconds)`` and records the timing
-    into ``metrics.histogram(name)`` / a tracer wall span when given."""
+    into ``metrics.histogram(name)`` / a tracer wall span when given.
+
+    A failed device computation (an out-of-memory, a refused kernel)
+    surfaces in the block and propagates: no timing is recorded for it."""
     t0 = time.perf_counter()
     with annotate(name):
-        out = _block(fn(*args, **kwargs))
+        out = jax.block_until_ready(fn(*args, **kwargs))
     dt = time.perf_counter() - t0
     if metrics is not None:
         metrics.histogram(name).observe(dt)

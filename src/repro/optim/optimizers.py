@@ -29,7 +29,7 @@ def cosine_lr(base: float, warmup: int, total: int):
 
 def sgd_init(params, momentum: float = 0.0):
     if momentum:
-        return {"mu": jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), params)}
+        return {"mu": jax.tree.map(lambda x: jnp.zeros_like(x, jnp.float32), params)}
     return {}
 
 
@@ -55,7 +55,9 @@ def sgd_update(params, grads, state, lr, momentum: float = 0.0,
 # --- AdamW ---------------------------------------------------------------
 
 def adamw_init(params):
-    z = lambda x: jnp.zeros(x.shape, jnp.float32)
+    # zeros_like: a moment is born where its parameter lives (sharded
+    # params get sharded moments, not one unsharded copy on device 0)
+    z = lambda x: jnp.zeros_like(x, jnp.float32)
     return {
         "m": jax.tree.map(z, params),
         "v": jax.tree.map(z, params),

@@ -81,13 +81,7 @@ _SPMD_SCRIPT = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    import inspect
-    _CK = ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-           else "check_rep")  # kwarg renamed across jax versions
+    from jax import shard_map
     from repro.core import aggregation as agg
     from repro.launch.mesh import make_test_mesh
 
@@ -107,7 +101,7 @@ _SPMD_SCRIPT = textwrap.dedent("""
     for mode in ("two_step", "classical"):
         fn = shard_map(lambda xs, ws: worker(xs, ws, mode), mesh=mesh,
                        in_specs=(P(("pod", "data")), P(("pod", "data"))),
-                       out_specs=(P(), P()), **{_CK: False})
+                       out_specs=(P(), P()), check_vma=False)
         m, K = jax.jit(fn)(x, w)
         outs[mode] = (np.asarray(m), float(K))
     want, Kw = agg.numpy_weighted_mean(np.asarray(x), np.asarray(w), np.ones(C))
@@ -117,7 +111,7 @@ _SPMD_SCRIPT = textwrap.dedent("""
     # int8-compressed cross-pod hop: unbiased, so close but not exact
     fn = shard_map(lambda xs, ws: worker(xs, ws, "two_step"), mesh=mesh,
                    in_specs=(P(("pod", "data")), P(("pod", "data"))),
-                   out_specs=(P(), P()), **{_CK: False})
+                   out_specs=(P(), P()), check_vma=False)
     print("SPMD_AGG_OK")
 """)
 
@@ -126,7 +120,8 @@ def test_two_step_collective_multidevice():
     """shard_map two-step == flat all-reduce == numpy, on a 2x4 fake mesh."""
     r = subprocess.run([sys.executable, "-c", _SPMD_SCRIPT],
                        capture_output=True, text=True,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"},
                        cwd=__import__("os").path.join(
                            __import__("os").path.dirname(__file__), ".."))
     assert "SPMD_AGG_OK" in r.stdout, r.stdout + r.stderr

@@ -159,7 +159,8 @@ def test_python_dash_m_repro_obs_diff_entrypoint(tmp_path):
     """The documented CLI shape: ``python -m repro.obs.diff A B``."""
     a = str(tmp_path / "a.json")
     _bundle(a, seed=3)
-    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"))
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO, "src"),
+               JAX_PLATFORMS="cpu")
     r = subprocess.run([sys.executable, "-m", "repro.obs.diff", a, a],
                        capture_output=True, text=True, env=env, cwd=REPO)
     assert r.returncode == 0, r.stderr
@@ -396,6 +397,22 @@ def test_regress_timing_is_warn_only_accuracy_drop_hard_fails():
     assert drop and all(f.status == "fail" for f in drop)
     # improvement is never a regression
     assert regress.compare(base, _mini_sweep(acc=0.9)) == []
+
+
+def test_regress_differing_dispatch_stamps_are_incomparable():
+    """A chip 'compiled' kernel row is never timed against a CPU 'ref'
+    row; a row without the stamp (an older artifact) is still compared."""
+    from benchmarks import regress
+    base, cand = _mini_sweep(), _mini_sweep(us=5.0)
+    base["kernels"][0].update(dispatch="ref", device="cpu:cpu")
+    cand["kernels"][0].update(dispatch="compiled", device="tpu:TPU v5 lite")
+    findings = regress.compare(base, cand)
+    assert sorted((f.metric, f.status) for f in findings) == [
+        ("device", "incomparable"), ("dispatch", "incomparable")]
+    del base["kernels"][0]["dispatch"], base["kernels"][0]["device"]
+    findings = regress.compare(base, cand)
+    assert findings and all(f.status == "warn" for f in findings)
+    assert {f.metric for f in findings} == {"us_per_call", "derived"}
 
 
 def test_regress_missing_rows_and_benches_are_findings():

@@ -20,7 +20,8 @@ REPO = os.path.join(os.path.dirname(__file__), "..")
 def _run(script: str) -> str:
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
                        capture_output=True, text=True,
-                       env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "")},
+                       env={"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+                            "JAX_PLATFORMS": "cpu"},
                        cwd=REPO, timeout=560)
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     return r.stdout
@@ -85,7 +86,10 @@ def test_serve_driver_runs():
 
 
 def test_train_driver_with_pon_and_checkpoint(tmp_path):
+    # the compile cache goes under tmp_path, not into the checkout
+    cache = f"import os; os.environ['JAX_COMPILATION_CACHE_DIR'] = r'{tmp_path / 'jax_cache'}'"
     out = _run(f"""
+        {cache}
         import sys
         sys.argv = ["train", "--arch", "qwen2-0.5b", "--smoke", "--steps", "3",
                     "--batch", "4", "--seq", "32", "--ckpt", r"{tmp_path}",
@@ -95,6 +99,7 @@ def test_train_driver_with_pon_and_checkpoint(tmp_path):
     """)
     assert "saved final" in out
     out2 = _run(f"""
+        {cache}
         import sys
         sys.argv = ["train", "--arch", "qwen2-0.5b", "--smoke", "--steps", "5",
                     "--batch", "4", "--seq", "32", "--ckpt", r"{tmp_path}"]
@@ -124,7 +129,7 @@ def test_dryrun_small_mesh_subprocess():
         mesh = make_test_mesh((2, 2, 2), ("pod", "data", "model"))
         rules = ShardingRules(batch=("pod", "data"), fsdp="data",
                               tensor="model", expert="model")
-        with mesh:
+        with jax.set_mesh(mesh):
             fn, args, _ = S.input_specs(cfg, shp, mesh, rules, "adamw")
             compiled = jax.jit(fn).lower(*args).compile()
             print("mem", compiled.memory_analysis().temp_size_in_bytes)
@@ -137,9 +142,6 @@ def test_dryrun_small_mesh_subprocess():
     assert "DRYRUN_OK" in out
 
 
-@pytest.mark.skipif(not hasattr(jax, "shard_map"),
-                    reason="old jaxlib: pre-0.8 XLA emits a collective HLO "
-                           "format the roofline parser does not cost")
 def test_sfl_vs_classical_cross_pod_traffic():
     """THE paper claim, on collectives: the SFL (FSDP two-step) schedule
     moves fewer cross-pod bytes than the classical flat all-reduce."""
@@ -169,3 +171,23 @@ def test_sfl_vs_classical_cross_pod_traffic():
         print("SFL_TRAFFIC_OK")
     """)
     assert "SFL_TRAFFIC_OK" in out
+
+
+@pytest.mark.parametrize("env_dir", [None, "from_env"])
+def test_compile_cache_location(tmp_path, env_dir):
+    """Unset JAX_COMPILATION_CACHE_DIR: the cache goes to the fixed in-tree
+    ``.jax_cache``; set: JAX reads the variable and nothing is set in code."""
+    env = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", ""),
+           "JAX_PLATFORMS": "cpu"}
+    want = os.path.realpath(os.path.join(REPO, ".jax_cache"))
+    if env_dir is not None:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    r = subprocess.run(
+        [sys.executable, "-c", textwrap.dedent("""
+            import jax
+            from repro.common.compile_cache import enable_compile_cache
+            print(enable_compile_cache())
+            print(jax.config.jax_compilation_cache_dir)
+        """)], capture_output=True, text=True, env=env, cwd=REPO, timeout=120)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [want, want]
