@@ -23,6 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import aggregation
+from repro.obs import profile
 from repro.pon import PonConfig, round_times
 
 
@@ -159,8 +160,10 @@ def train_selected_clients(global_params, client_batches, loss_fn: Callable,
     deltas, losses = [], []
     fn = jax.vmap(one_client)
     for lo in range(0, n_sel, chunk):
-        cb = jax.tree.map(lambda x: x[lo:lo + chunk], client_batches)
-        d, l = fn(cb)
+        with profile.span("train.chunk", chunk=lo // chunk,
+                          rows=min(chunk, n_sel - lo)):
+            cb = jax.tree.map(lambda x: x[lo:lo + chunk], client_batches)
+            d, l = fn(cb)
         deltas.append(d)
         losses.append(l)
     deltas = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *deltas)

@@ -96,7 +96,7 @@ class ClientStackedBackend:
         obs = _obs_get()
         (loss, metrics), _ = profile.timed(
             "backend.eval_s", self.loss_fn, self.params, self.eval_batch,
-            metrics=obs.metrics, tracer=obs.tracer)
+            metrics=obs.metrics)
         out = {"eval_loss": float(loss)}
         out.update({k: float(v) for k, v in metrics.items()})
         self._last_eval = out
@@ -111,12 +111,12 @@ class ClientStackedBackend:
         """Shared tail of both regimes: strategy aggregate → server update
         → uplink stats + eval cadence (any change here changes the sync
         run_round and the async apply_updates together)."""
-        agg, stats = self.strategy.aggregate(stacked, weights, mask, onu_ids,
-                                             self.fl.total_onus,
-                                             comp=self._comp,
-                                             client_ids=client_ids)
-        self.params, self.server_state = self.strategy.server_update(
-            self.params, agg, self.server_state)
+        with profile.span("round.aggregate"):
+            agg, stats = self.strategy.aggregate(
+                stacked, weights, mask, onu_ids, self.fl.total_onus,
+                comp=self._comp, client_ids=client_ids)
+            self.params, self.server_state = self.strategy.server_update(
+                self.params, agg, self.server_state)
         out = {"uplink_models": float(stats["uplink_models"])}
         if (rnd + 1) % self.eval_every == 0:
             out.update(self._eval())
@@ -137,15 +137,18 @@ class ClientStackedBackend:
         pad = (-len(active)) % fl.client_chunk
         padded = np.concatenate([active, np.full(pad, active[0])])
         w = np.concatenate([self.sample_counts[active], np.zeros(pad, np.float32)])
-        cb = jax.tree.map(
-            lambda *xs: jnp.stack(xs),
-            *[self.minibatch_fn(rng, self.clients[c], fl.local_steps,
-                                fl.local_batch) for c in padded])
+        with profile.span("round.minibatch", n_clients=len(padded)) as sp:
+            cb = jax.tree.map(
+                lambda *xs: jnp.stack(xs),
+                *[self.minibatch_fn(rng, self.clients[c], fl.local_steps,
+                                    fl.local_batch) for c in padded])
+            sp.set_metadata(h2d_bytes=sum(
+                x.nbytes for x in jax.tree.leaves(cb)))
         obs = _obs_get()
         (deltas, _), _ = profile.timed(
             "backend.train_s", fedavg.train_selected_clients,
             self.params, cb, self.loss_fn, fl,
-            metrics=obs.metrics, tracer=obs.tracer,
+            metrics=obs.metrics,
             local_update=self.strategy.local_update)
         return self._apply_and_eval(
             rnd, deltas, jnp.asarray(w),
@@ -293,7 +296,7 @@ class GradientBackend:
         (self.params, self.opt_state, loss), dt = profile.timed(
             "backend.train_step_s", self.train_step,
             self.params, self.opt_state, batch,
-            metrics=obs.metrics, tracer=obs.tracer)
+            metrics=obs.metrics)
         return {"loss": float(loss), "dt": dt}
 
 

@@ -33,6 +33,7 @@ import numpy as np
 from repro.core import selection
 from repro.fl.backends import backend_wire_scale
 from repro.fl.config import ExperimentConfig
+from repro.obs import profile
 from repro.obs.context import Obs
 from repro.obs.context import get as _obs_get
 from repro.pon import round_times
@@ -157,12 +158,16 @@ def sync_round(cfg: ExperimentConfig, backend, failures,
         trc.offset_s = rnd * window
         trc.add_span("round", 0.0, window, lane=("fl", "rounds"), cat="round",
                      args={"round": rnd})
-    sel, mask, rt = _transport_stage(cfg, backend, failures, rng, rnd, obs)
+    with profile.span("round.transport") as sp:
+        sel, mask, rt = _transport_stage(cfg, backend, failures, rng, rnd,
+                                         obs)
+        involved = float(mask.sum())
+        sp.set_metadata(n_selected=len(sel), involved=involved)
     metrics = backend.run_round(rnd, sel, mask, rt, rng)
     reg = obs.metrics
     rec = {"round": rnd, "n_selected": len(sel),
            "sim_engine": rt.get("sim_engine", "event"),
-           "involved": float(mask.sum())}
+           "involved": involved}
     reg.histogram("fl.involved").observe(rec["involved"])
     # per-segment accounting from the transport (DESIGN.md §12)
     for key, cname in _SEG_COUNTERS.items():

@@ -44,7 +44,7 @@ def dispatch_mode(use_pallas: Optional[bool] = None) -> str:
 # jax.named_scope names the HLO emitted under each kernel, so device
 # profiles (and jax.profiler captures) show agg_reduce/quantize/... as
 # named regions regardless of dispatch mode — the in-jit counterpart of
-# repro.obs.profile.annotate
+# repro.obs.profile.span
 
 @functools.partial(jax.jit, static_argnames=("use_pallas",))
 def agg_reduce(x, weights, mask, use_pallas: Optional[bool] = None):

@@ -26,8 +26,11 @@ from repro.obs.audit.bundle import RunReport
 
 # metric/row keys matching these fragments measure host time — two healthy
 # runs will not agree, so differences are warnings rather than diffs
-# (dt / backend.train_step_s are the drivers' wall-clock step timings)
-_WARN_FRAGMENTS = ("wall", "us_per_call", "host_s", "step_s", "compile")
+# (dt / backend.train_step_s are the drivers' wall-clock step timings;
+# jax.* are JAX's compile-pipeline counters, whose seconds are host time
+# and whose counts follow the compile caches' state)
+_WARN_FRAGMENTS = ("wall", "us_per_call", "host_s", "step_s", "compile",
+                   "jax.")
 _WARN_EXACT = ("dt",)
 
 
@@ -156,13 +159,17 @@ def _diff_metrics(a: RunReport, b: RunReport, rtol: float,
     for key in sorted(set(ia) | set(ib), key=str):
         kind, name = key
         label = f"{kind}:{name}"
+        # a host-time metric that one run never recorded (a program read
+        # from the cache records no jax.compiles) is a warning as well
         if key not in ia:
-            entries.append(DiffEntry("metrics", label, None, "present",
-                                     status="missing_a"))
+            entries.append(DiffEntry(
+                "metrics", label, None, "present",
+                status="warn" if _is_warn_key(name) else "missing_a"))
             continue
         if key not in ib:
-            entries.append(DiffEntry("metrics", label, "present", None,
-                                     status="missing_b"))
+            entries.append(DiffEntry(
+                "metrics", label, "present", None,
+                status="warn" if _is_warn_key(name) else "missing_b"))
             continue
         ra, rb = ia[key], ib[key]
         for field in sorted(set(ra) | set(rb)):

@@ -92,7 +92,7 @@ class Tracer:
         ``offset_s`` shifts retroactive per-round emissions onto one
         global timeline (round *r* of a lockstep driver starts at
         ``r × window``).
-      * **wall seconds** — :meth:`wall_span` measures host time
+      * **wall seconds** — ``repro.obs.profile.span`` measures host time
         (``time.perf_counter`` relative to tracer creation) onto
         ``wall:*`` lanes, kept separate so simulated and real time are
         never conflated on one track.
@@ -125,15 +125,10 @@ class Tracer:
         """Context manager span on a live clock callable (sim by default
         only if ``clock`` is given; pass ``SimClock``'s ``lambda: clock.now``)."""
         if clock is None:
-            raise ValueError("span() needs a clock callable; use wall_span() "
-                             "for host time or add_span() for known intervals")
+            raise ValueError("span() needs a clock callable; use "
+                             "repro.obs.profile.span() for host time or "
+                             "add_span() for known intervals")
         return _SpanCtx(self, name, lane, cat, args, clock)
-
-    def wall_span(self, name: str, lane_tid: str = "host", cat: str = "wall",
-                  args: Optional[Dict[str, Any]] = None) -> _SpanCtx:
-        """Context manager measuring wall time onto the ``wall:*`` lanes."""
-        return _SpanCtx(self, name, ("wall", lane_tid), cat, args,
-                        self._wall_now)
 
     def _wall_now(self) -> float:
         # wall spans bypass offset_s: subtract it back out at record time
@@ -227,9 +222,6 @@ class NoopTracer:
         pass
 
     def span(self, *a, **k) -> "_NullCtx":
-        return _NULL_CTX
-
-    def wall_span(self, *a, **k) -> "_NullCtx":
         return _NULL_CTX
 
     def to_chrome(self) -> Dict[str, Any]:

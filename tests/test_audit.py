@@ -62,13 +62,17 @@ def _backend(exp, mode="sfl"):
         fl.make_strategy(mode, **dict(exp.strategy_kwargs)), counts, onu)
 
 
-def _bundle(path, seed=3, mode="sfl", health=False):
-    """One full driver run through an ObsSession with --report-out."""
+def _bundle(path, seed=3, mode="sfl", health=False, jitted=None):
+    """One full driver run through an ObsSession with --report-out;
+    ``jitted`` is called inside it, so JAX's compile pipeline records
+    into the run's registry."""
     exp = _exp(seed=seed, mode=mode)
     sess = obs.session(report_out=str(path), health=health, driver="round_loop")
     try:
         loop = fl.RoundLoop(exp, _backend(exp, mode))
         hist = loop.run()
+        if jitted is not None:
+            jitted(np.ones(3, np.float32)).block_until_ready()
     finally:
         sess.finish(quiet=True, cfg=exp, history=hist)
     return RunReport.load(str(path))
@@ -116,6 +120,27 @@ def test_same_config_and_seed_diffs_to_zero(tmp_path):
     assert diff.n_diffs == 0, [e.line() for e in diff.entries]
     assert diff.exit_code == 0
     assert diff.first_divergence["round"] is None
+
+
+@pytest.mark.parametrize("second", ["compiled_again", "cached"])
+def test_same_seed_diffs_to_zero_whatever_jax_compiled(tmp_path, second):
+    """Two same-config+seed runs whose JAX compile work differs (the
+    second compiles the program again, or finds it compiled and records
+    no jax.* counter at all, as a persistent-cache read would differ)
+    still report zero hard diffs: the jax.* counters are warnings."""
+    import jax
+
+    f = jax.jit(lambda v: v * 2 + 1)
+    a = _bundle(tmp_path / "a.json", seed=3, jitted=f)
+    g = f if second == "cached" else jax.jit(lambda v: v * 2 + 1)
+    b = _bundle(tmp_path / "b.json", seed=3, jitted=g)
+    names = {m["name"] for m in a.metrics}
+    assert {"jax.traces", "jax.trace_s", "jax.lower_s"} <= names
+    if second == "cached":
+        assert not any(m["name"].startswith("jax.") for m in b.metrics)
+    diff = diff_bundles(a, b)
+    assert diff.n_diffs == 0, [e.line() for e in diff.entries]
+    assert diff.n_warns > 0 and diff.exit_code == 0
 
 
 def test_differing_seeds_localize_first_diverging_round(tmp_path):

@@ -10,6 +10,7 @@ import pytest
 from hypothesis_compat import given, settings, st
 from repro import fl, obs
 from repro.core.fedavg import FLConfig
+from repro.obs import profile
 from repro.obs.context import Obs
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
 from repro.obs.tracer import NOOP_TRACER, Tracer
@@ -42,7 +43,7 @@ def test_wall_spans_unaffected_by_sim_offset():
     t = Tracer()
     t.offset_s = 1000.0          # retro per-round shift on the sim axis
     t.add_span("sim", 0.0, 1.0)
-    with t.wall_span("host-work"):
+    with obs.use(Obs(tracer=t)), profile.span("host-work"):
         pass
     sim_span, wall_span = t.spans
     assert (sim_span.t0_s, sim_span.t1_s) == (1000.0, 1001.0)
@@ -85,7 +86,7 @@ def test_chrome_export_schema(tmp_path):
 def test_noop_tracer_is_allocation_free_on_hot_paths():
     assert not NOOP_TRACER.enabled
     # span contexts are one shared singleton — no per-call allocation
-    assert NOOP_TRACER.span("x") is NOOP_TRACER.wall_span("y")
+    assert NOOP_TRACER.span("x") is NOOP_TRACER.span("y")
     NOOP_TRACER.add_span("x", 0, 1)
     assert NOOP_TRACER.spans == ()
     assert NOOP_TRACER.to_chrome()["traceEvents"] == []
@@ -416,3 +417,61 @@ def test_disabled_session_is_noop_and_writes_nothing(tmp_path):
         sess.finish(quiet=True)
     assert obs.get() is prev
     assert list(tmp_path.iterdir()) == []
+
+
+# ------------------------------------------------------- program spans / jax
+
+def test_profile_span_is_a_bare_trace_annotation_when_untraced():
+    """No tracer: the span costs what a TraceAnnotation costs."""
+    import jax
+    with obs.use(Obs.disabled()):
+        sp = profile.span("train.chunk", chunk=0, rows=16)
+        assert type(sp) is jax.profiler.TraceAnnotation
+        with sp:
+            sp.set_metadata(extra=1)
+
+
+def test_profile_span_puts_its_stats_on_the_wall_lane():
+    t = Tracer()
+    with obs.use(Obs(tracer=t)):
+        with profile.span("round.minibatch", n_clients=4) as sp:
+            sp.set_metadata(h2d_bytes=128)
+        out, dt = profile.timed("backend.eval_s", lambda: 1.0)
+    assert out == 1.0 and dt >= 0
+    mb, ev = t.spans
+    assert (mb.name, mb.lane, mb.args) == (
+        "round.minibatch", ("wall", "compute"),
+        {"n_clients": 4, "h2d_bytes": 128})
+    assert ev.name == "backend.eval_s" and ev.args is None
+    assert mb.t1_s <= ev.t0_s
+
+
+def test_fresh_jit_counts_one_program_into_metrics_out(tmp_path):
+    """A jit made afresh traces, lowers and compiles (or reads the
+    persistent cache) once; calling it again obtains nothing; operators
+    read the jax.* counters from --metrics-out. Installing the listener
+    again adds none: each event still counts once."""
+    import jax
+    import jax.numpy as jnp
+
+    profile.watch_jax()
+
+    names = [n for pair in profile.COUNTERS.values() for n in pair]
+    path = str(tmp_path / "m.jsonl")
+    x = jnp.ones((3, 5))
+    sess = obs.session(metrics_out=path)
+    try:
+        reg = sess.metrics
+        f = jax.jit(lambda v: jax.lax.neg(v))
+        f(x).block_until_ready()
+        first = {n: reg.counter(n).total for n in names}
+        f(x).block_until_ready()
+        second = {n: reg.counter(n).total for n in names}
+    finally:
+        sess.finish(quiet=True)
+    assert first["jax.traces"] == 1 and first["jax.lowerings"] == 1
+    assert first["jax.compiles"] + first["jax.cache_reads"] == 1
+    assert first["jax.compile_s"] + first["jax.cache_read_s"] > 0
+    assert second == first
+    totals = {r["name"]: r["total"] for r in obs.read_jsonl(path)}
+    assert totals["jax.lowerings"] == 1
