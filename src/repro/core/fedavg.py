@@ -140,30 +140,50 @@ def default_local_update(global_params, batches, loss_fn: Callable,
     return delta, l
 
 
-def train_selected_clients(global_params, client_batches, loss_fn: Callable,
-                           fl: FLConfig, local_update: Optional[Callable] = None):
-    """Run local training for all selected clients; returns stacked deltas.
+def chunk_program(loss_fn: Callable, fl: FLConfig,
+                  local_update: Optional[Callable] = None) -> Callable:
+    """The jitted local-train program for one chunk of clients.
 
-    client_batches: dict of arrays with leading (n_sel, steps, batch, ...)
-    axes. vmap is chunked (client_chunk at a time) to bound host memory.
-    ``local_update(global_params, batches, loss_fn, fl) -> (delta, loss)``
-    is the per-client rule (a ``repro.fl`` Strategy hook); default FedAvg.
+    ``program(global_params, chunk_batches) -> (deltas, losses)``: the
+    per-client rule vmapped over the chunk's leading axis, the global
+    params broadcast. They are an argument, not a closed-over constant, so
+    one program serves every round; ``loss_fn``, ``fl`` and
+    ``local_update`` are static. Hold it across calls: a fresh ``jax.jit``
+    traces, lowers and compiles (or reads the persistent cache) again.
     """
     if local_update is None:
         local_update = default_local_update
 
-    def one_client(batches):
+    def local_train_chunk(global_params, batches):
         return local_update(global_params, batches, loss_fn, fl)
+
+    return jax.jit(jax.vmap(local_train_chunk, in_axes=(None, 0)))
+
+
+def train_selected_clients(global_params, client_batches, loss_fn: Callable,
+                           fl: FLConfig, local_update: Optional[Callable] = None,
+                           program: Optional[Callable] = None):
+    """Run local training for all selected clients; returns stacked deltas.
+
+    client_batches: dict of arrays with leading (n_sel, steps, batch, ...)
+    axes, trained ``client_chunk`` clients at a time to bound memory.
+    ``local_update(global_params, batches, loss_fn, fl) -> (delta, loss)``
+    is the per-client rule (a ``repro.fl`` Strategy hook); default FedAvg.
+    ``program`` is a :func:`chunk_program` held by the caller, reused by
+    every chunk of every call (it supersedes ``local_update``); without
+    one, each call builds its own.
+    """
+    if program is None:
+        program = chunk_program(loss_fn, fl, local_update)
 
     n_sel = jax.tree.leaves(client_batches)[0].shape[0]
     chunk = max(1, min(fl.client_chunk, n_sel))
     deltas, losses = [], []
-    fn = jax.vmap(one_client)
     for lo in range(0, n_sel, chunk):
         with profile.span("train.chunk", chunk=lo // chunk,
                           rows=min(chunk, n_sel - lo)):
             cb = jax.tree.map(lambda x: x[lo:lo + chunk], client_batches)
-            d, l = fn(cb)
+            d, l = program(global_params, cb)
         deltas.append(d)
         losses.append(l)
     deltas = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *deltas)

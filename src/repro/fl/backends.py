@@ -86,6 +86,7 @@ class ClientStackedBackend:
         self.minibatch_fn = minibatch_fn
         self._last_eval: Dict[str, float] = {}
         self._one_client = None     # lazily-jitted single-client update
+        self._chunk_program = None  # lazily-jitted chunk of client updates
         # wire compression (DESIGN.md §17): the backend owns the stateful
         # side — EF residuals + the rounding key stream — so the frozen
         # Strategy stays pure and ``compress=none`` allocates nothing
@@ -144,12 +145,15 @@ class ClientStackedBackend:
                                     fl.local_batch) for c in padded])
             sp.set_metadata(h2d_bytes=sum(
                 x.nbytes for x in jax.tree.leaves(cb)))
+        if self._chunk_program is None:
+            # held across rounds: one trace, lowering and compile in all
+            self._chunk_program = fedavg.chunk_program(
+                self.loss_fn, fl, self.strategy.local_update)
         obs = _obs_get()
         (deltas, _), _ = profile.timed(
             "backend.train_s", fedavg.train_selected_clients,
             self.params, cb, self.loss_fn, fl,
-            metrics=obs.metrics,
-            local_update=self.strategy.local_update)
+            metrics=obs.metrics, program=self._chunk_program)
         return self._apply_and_eval(
             rnd, deltas, jnp.asarray(w),
             jnp.concatenate([jnp.ones(len(active)), jnp.zeros(pad)]),

@@ -165,6 +165,77 @@ def test_roundloop_bit_for_bit_vs_prerefactor_trajectory():
         assert old[mode]["involved"] == new[mode]["involved"], mode
 
 
+def _eager_chunks(params, cb, flc, local_update):
+    """The per-call eager ``jax.vmap`` that the held chunk program replaced."""
+    n_sel = jax.tree.leaves(cb)[0].shape[0]
+    fn = jax.vmap(lambda b: local_update(params, b, _loss, flc))
+    out = [fn(jax.tree.map(lambda x: x[lo:lo + flc.client_chunk], cb))
+           for lo in range(0, n_sel, flc.client_chunk)]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *out)
+
+
+@pytest.mark.parametrize("strategy,kw", [("sfl_two_step", {}),
+                                         ("fedprox", {"mu": 0.3})],
+                         ids=["fedavg", "fedprox"])
+def test_chunk_program_matches_eager_vmap(strategy, kw):
+    """The held chunk program computes what the eager per-call vmap did,
+    round after round with new global params, ragged last chunk included."""
+    cfg = configs.get("femnist_cnn").reduced()
+    params, _ = femnist_cnn.init_params(cfg, jax.random.PRNGKey(0))
+    clients, _ = femnist.generate(femnist.FemnistConfig(n_clients=6, seed=11))
+    flc = FLConfig(local_steps=3, local_batch=8, local_lr=0.05,
+                   client_chunk=4)
+    rng = np.random.default_rng(0)
+    cb = jax.tree.map(lambda *xs: jnp.stack(xs), *[
+        femnist.client_minibatches(rng, c, flc.local_steps, flc.local_batch)
+        for c in clients])
+    lu = fl.make_strategy(strategy, **kw).local_update
+    program = fedavg.chunk_program(_loss, flc, lu)
+    for _ in range(2):
+        deltas, losses = fedavg.train_selected_clients(
+            params, cb, _loss, flc, program=program)
+        ref_deltas, ref_losses = _eager_chunks(params, cb, flc, lu)
+        assert losses.shape == (6,)
+        np.testing.assert_allclose(np.asarray(losses),
+                                   np.asarray(ref_losses), rtol=1e-5)
+        for a, b in zip(jax.tree.leaves(deltas), jax.tree.leaves(ref_deltas)):
+            assert a.shape[0] == 6
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-7)
+        params = jax.tree.map(lambda p, d: p + d[0], params, deltas)
+
+
+def test_backend_builds_its_chunk_program_once():
+    """Rounds after the first trace and lower nothing while training: the
+    chunk program takes the global params as an argument and outlives the
+    round."""
+    from repro import obs
+    from repro.obs.context import Obs
+    from repro.obs.tracer import Tracer
+
+    cfg = configs.get("femnist_cnn").reduced()
+    params, _ = femnist_cnn.init_params(cfg, jax.random.PRNGKey(0))
+    pon = PonConfig(n_onus=4, clients_per_onu=5)
+    flc = FLConfig(n_onus=4, clients_per_onu=5, n_selected=8, local_steps=2,
+                   client_chunk=4, pon=pon)
+    clients, eval_set = femnist.generate(
+        femnist.FemnistConfig(n_clients=flc.n_clients, seed=11))
+    backend = fl.ClientStackedBackend(
+        flc, fl.make_strategy("sfl_two_step"), params, clients,
+        jax.tree.map(jnp.asarray, eval_set), _loss)
+    t = Tracer()
+    with obs.use(Obs(tracer=t)):
+        fl.RoundLoop(fl.ExperimentConfig(fl=flc, n_rounds=3), backend).run()
+    train = [s for s in t.spans if s.name == "backend.train_s"]
+    chunks = [s for s in t.spans if s.name == "train.chunk"]
+    stages = [s for s in t.spans if s.name in ("jax.trace", "jax.lower")]
+    built = [sum(tr.t0_s <= s.t1_s <= tr.t1_s for s in stages)
+             for tr in train]
+    assert len(train) == 3 and len(chunks) == 6
+    assert built[0] >= 1 and built[1:] == [0, 0], built
+    assert backend._chunk_program._cache_size() == 1
+
+
 def _transport_loop(n_selected=10, **exp_kw):
     pon = PonConfig(n_onus=4, clients_per_onu=5)
     flc = FLConfig(n_onus=4, clients_per_onu=5, n_selected=n_selected, pon=pon)

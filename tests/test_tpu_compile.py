@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro import configs, fl
+from repro.core import fedavg
 from repro.core.fedavg import FLConfig
 from repro.kernels import agg_reduce as agg_k
 from repro.kernels import quantize as quant_k
@@ -102,11 +103,9 @@ def test_client_stacked_chunk_step_compiles_for_v5e(one_chip):
         "images": jax.ShapeDtypeStruct((chunk, H, B, 28, 28, 1), jnp.float32),
         "labels": jax.ShapeDtypeStruct((chunk, H, B), jnp.int32)})
 
-    def local_chunk(p, cb):
-        return jax.vmap(lambda b: strategy.local_update(
-            p, b, femnist_cnn.loss_fn, flc))(cb)
-
-    train = jax.jit(local_chunk).lower(place(params), batches).compile()
+    program = fedavg.chunk_program(femnist_cnn.loss_fn, flc,
+                                   strategy.local_update)
+    train = program.lower(place(params), batches).compile()
     _fits(train)
 
     rows = flc.n_selected
