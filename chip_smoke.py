@@ -281,9 +281,10 @@ def mesh_phase(*, model_cfg, batch: int = 8, seq: int = 128,
     assert steps >= 2, "the control stops one step short"
     devices = jax.devices()
     n_dev = len(devices)
-    rng = np.random.default_rng(seed)
     out, snaps = {}, {}
     for name in ("sfl", "classical"):
+        # the same round stream in both runs: the same clients' tokens
+        rng = np.random.default_rng(seed)
         exp = fl.ExperimentConfig(strategy=name, seed=seed).with_fl(
             n_selected=batch)
         backend = train.make_backend(model_cfg, exp, batch=batch, seq=seq,

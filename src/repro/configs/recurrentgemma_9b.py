@@ -11,5 +11,5 @@ CONFIG = ModelConfig(
     name="recurrentgemma-9b", family="hybrid",
     n_layers=38, d_model=4096, n_heads=16, n_kv_heads=1, d_ff=12288,
     vocab_size=256000, head_dim=256, window=2048,
-    block_pattern=("rglru", "rglru", "attn"),
+    block_pattern=("rglru", "rglru", "attn"), embed_scale=True,
 )

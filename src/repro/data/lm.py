@@ -43,3 +43,29 @@ def client_lm_batches(seed: int, client_id: int, steps: int, batch: int,
     rng = np.random.default_rng(seed * 100003 + client_id)
     toks = markov_tokens(rng, steps * batch * seq_len, vocab)
     return {"tokens": toks.reshape(steps, batch, seq_len)}
+
+
+# sequences each client holds of its own stream (the default round batch)
+CLIENT_ROWS = 8
+
+
+def client_rows(seed: int, seq_len: int, vocab: int,
+                n_rows: int = CLIENT_ROWS):
+    """A round-batch function ``(rng, clients) -> (len(clients), seq_len)``
+    int32 tokens: row r is one of client ``clients[r]``'s ``n_rows``
+    sequences of its own stream (``client_lm_batches(seed, c, n_rows, 1,
+    ...)``), the sequence drawn from ``rng`` in row order. A client's
+    sequences are made the first time it is seen and kept."""
+    streams: Dict[int, np.ndarray] = {}
+
+    def rows(rng: np.random.Generator, clients) -> np.ndarray:
+        idx = rng.integers(0, n_rows, size=len(clients))
+        out = []
+        for c, i in zip(clients, idx):
+            c = int(c)
+            if c not in streams:
+                streams[c] = client_lm_batches(seed, c, n_rows, 1, seq_len,
+                                               vocab)["tokens"][:, 0]
+            out.append(streams[c][i])
+        return np.stack(out)
+    return rows

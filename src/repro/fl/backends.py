@@ -19,7 +19,7 @@ owns selection, failures, and PON transport. Contract:
     sweeps (DBA policies, wavelengths, background load).
 
 Two optional extensions (implemented by ClientStacked/Transport, used by
-``repro.runtime``):
+``repro.runtime``; ``replay_round`` by Gradient too):
 
     backend.replay_round(rnd, selected, mask, rt, rng)
         — consume exactly run_round's RNG draws without training, so a
@@ -221,6 +221,14 @@ class GradientBackend:
     the sharding rules (two-step FSDP schedule vs replicated flat
     all-reduce), so the collective form of the paper's aggregation is
     induced by the same Strategy object the client-stacked regime uses.
+
+    ``params``: the weights, made by the caller (drawn from ``seed`` when
+    None), placed by the logical axes of ``init_params(abstract=True)``.
+    ``batch_fn(rng, clients) -> (batch, seq)`` int tokens: row r holds
+    client ``clients[r]``'s own data, drawn from the round's ``rng``
+    (default ``data.lm.client_rows``: each client's own Markov stream).
+    The rows are the selected clients in selection order (over-selected:
+    involved ones first), padded with copies of the first at weight 0.
     """
 
     def __init__(self, model_cfg, strategy: Strategy, mesh, rules,
@@ -229,12 +237,14 @@ class GradientBackend:
                  seed: int = 0,
                  sample_counts: Optional[np.ndarray] = None,
                  onu_ids: Optional[np.ndarray] = None,
-                 n_clients: Optional[int] = None):
+                 n_clients: Optional[int] = None,
+                 params=None, batch_fn: Optional[Callable] = None):
         # model/data imports are lazy so `import repro.fl` stays light for
         # the client-stacked path
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.common.sharding import param_sharding_tree
+        from repro.data import lm as lm_data
         from repro.launch import specs as S
         from repro.models import transformer
         from repro.optim import make_optimizer
@@ -244,15 +254,19 @@ class GradientBackend:
         self.mesh = mesh
         self.batch = batch
         self.seq = seq
-        self.seed = seed
         n = n_clients if n_clients is not None else batch
         rng = np.random.default_rng(seed)
         self.sample_counts = (sample_counts if sample_counts is not None
                               else rng.integers(50, 400, n).astype(np.float32))
         self.onu_ids = (onu_ids if onu_ids is not None
                         else np.zeros(len(self.sample_counts), np.int64))
-        params, logical = transformer.init_params(model_cfg,
-                                                  jax.random.PRNGKey(seed))
+        self.batch_fn = batch_fn or lm_data.client_rows(
+            seed, seq, model_cfg.vocab_size)
+        tp = mesh.shape.get("model", 1)
+        _, logical = transformer.init_params(model_cfg, abstract=True, tp=tp)
+        if params is None:
+            params, _ = transformer.init_params(
+                model_cfg, jax.random.PRNGKey(seed), tp=tp)
         # place every leaf by its logical axes (FSDP rules shard it over
         # the mesh, replicated rules copy it to every device): left
         # unplaced, the step keeps sharded only what its constraints pin
@@ -266,6 +280,10 @@ class GradientBackend:
             lambda x: (x.sharding if isinstance(x.sharding, NamedSharding)
                        else replicated), opt_state)
         self.opt_state = jax.device_put(opt_state, opt_shard)
+        # rows over the batch axes, as the step reads them
+        self._row_shard = {
+            "tokens": S._batch_spec(mesh, rules, (batch, seq)),
+            "client_weight": S._batch_spec(mesh, rules, (batch,))}
         # the step hands its state back as it was placed: left to choose,
         # the compiler spells the same layouts differently (a size-1 axis
         # dropped), and the second step misses the jit cache and recompiles
@@ -274,34 +292,49 @@ class GradientBackend:
                               seed=seed),
             out_shardings=(shard, opt_shard, replicated))
 
-    def run_round(self, rnd: int, selected: np.ndarray, mask: np.ndarray,
-                  rt: Dict[str, Any], rng: np.random.Generator
-                  ) -> Dict[str, float]:
-        from repro.data import lm as lm_data
-        weights = (self.sample_counts[selected] * mask).astype(np.float32)
-        if len(weights) > self.batch:
+    def _rows(self, selected: np.ndarray, mask: np.ndarray):
+        """The round's row clients and their weights (k · mask)."""
+        rows = np.arange(len(selected))
+        if len(selected) > self.batch:
             # over-selection: more clients than batch rows — involved
             # clients (selection order) fill the rows first, so backups
             # replace deadline stragglers instead of starving the round
-            order = np.concatenate([np.where(mask > 0)[0],
-                                    np.where(mask <= 0)[0]])
-            weights = weights[order[:self.batch]]
-        elif len(weights) < self.batch:
-            weights = np.concatenate(
-                [weights, np.zeros(self.batch - len(weights), np.float32)])
-        batch_np = next(lm_data.lm_batches(
-            self.seed * 1000 + rnd, 1, self.batch, self.seq,
-            self.cfg.vocab_size))
-        batch = {
-            "tokens": jnp.asarray(batch_np["tokens"]),
-            "client_weight": jnp.asarray(weights, jnp.float32),
-        }
+            rows = np.concatenate([np.where(mask > 0)[0],
+                                   np.where(mask <= 0)[0]])[:self.batch]
+        clients = selected[rows]
+        weights = (self.sample_counts[clients] * mask[rows]).astype(np.float32)
+        pad = self.batch - len(clients)
+        if pad > 0:
+            clients = np.concatenate([clients, np.full(pad, clients[0])])
+            weights = np.concatenate([weights, np.zeros(pad, np.float32)])
+        return clients, weights
+
+    def run_round(self, rnd: int, selected: np.ndarray, mask: np.ndarray,
+                  rt: Dict[str, Any], rng: np.random.Generator
+                  ) -> Dict[str, float]:
+        clients, weights = self._rows(selected, mask)
+        weighted = int(np.sum(weights > 0))
+        with profile.span("round.minibatch", rows=self.batch,
+                          tokens=self.batch * self.seq) as sp:
+            host = {"tokens": np.asarray(self.batch_fn(rng, clients),
+                                         np.int32),
+                    "client_weight": weights}
+            batch = jax.block_until_ready(jax.device_put(host,
+                                                         self._row_shard))
+            sp.set_metadata(h2d_bytes=sum(x.nbytes for x in host.values()))
         obs = _obs_get()
+        obs.metrics.counter("lm.tokens").add(float(weighted * self.seq))
         (self.params, self.opt_state, loss), dt = profile.timed(
             "backend.train_step_s", self.train_step,
             self.params, self.opt_state, batch,
-            metrics=obs.metrics)
+            metrics=obs.metrics, span_stats={"rows_weighted": weighted})
         return {"loss": float(loss), "dt": dt}
+
+    def replay_round(self, rnd: int, selected: np.ndarray, mask: np.ndarray,
+                     rt: Dict[str, Any], rng: np.random.Generator) -> None:
+        """Consume run_round's token draws without training (resume
+        fast-forward — must mirror run_round's rng consumption exactly)."""
+        self.batch_fn(rng, self._rows(selected, mask)[0])
 
 
 class TransportBackend:

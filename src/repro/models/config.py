@@ -38,6 +38,9 @@ class ModelConfig:
     rope_theta: float = 1e4
     window: int = 0                # sliding window for 'attn' layers; 0 = global
     norm: str = "rms"              # rms|ln|nonparam  (olmo: nonparam)
+    norm_eps: float = 1e-6         # olmo: 1e-5
+    embed_scale: bool = False      # x * sqrt(d_model) after the embedding
+                                   # lookup (the Gemma convention)
     mlp: str = "swiglu"            # swiglu|gelu
     logit_softcap: float = 0.0
 

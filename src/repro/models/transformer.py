@@ -98,13 +98,15 @@ def init_params(cfg: ModelConfig, key=None, abstract: bool = False, tp: int = 16
         params["unit"] = jax.tree.map(
             lambda x: jax.ShapeDtypeStruct((n,) + tuple(x.shape), x.dtype), params["unit"])
     else:
-        # re-init stacked: draw (n, ...) in one shot for distinct per-layer values
-        key2 = jax.random.PRNGKey(hash(cfg.name) % (2**31))
+        # re-init stacked: draw (n, ...) in one shot for distinct per-layer
+        # values, from the caller's key (the same key gives the same weights
+        # in every process)
+        key2 = jax.random.fold_in(key, n)
         flat, treedef = jax.tree.flatten(params["unit"])
         new = []
         for i, x in enumerate(flat):
             key2, sub = jax.random.split(key2)
-            if np.issubdtype(x.dtype, np.floating) and x.ndim >= 2:
+            if jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 2:
                 std = 1.0 / np.sqrt(max(1, x.shape[0]))
                 new.append((jax.random.normal(sub, (n,) + x.shape, jnp.float32) * std
                             ).astype(x.dtype))
@@ -126,7 +128,7 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
     """Returns (x, new_cache). cache=None in training."""
     aux = 0.0
     if kind in ("attn", "cross"):
-        h = L.norm(x, p["norm1"], cfg.norm)
+        h = L.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
         if kind == "attn":
             window = cfg.window
             a, new_cache = L.self_attention(
@@ -136,7 +138,7 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
             a = L.cross_attention(h, p["attn"], cfg, rules, media)
             new_cache = cache if cache is not None else None
         x = x + a
-        h = L.norm(x, p["norm2"], cfg.norm)
+        h = L.norm(x, p["norm2"], cfg.norm, cfg.norm_eps)
         if cfg.n_experts:
             mo, aux = M.moe_block(h, p["moe"], cfg, rules)
             if cfg.dense_residual:
@@ -145,18 +147,18 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
             mo = L.mlp_block(h, p["mlp"], cfg, rules)
         x = x + mo
     elif kind == "rglru":
-        h = L.norm(x, p["norm1"], cfg.norm)
+        h = L.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
         a, new_cache = R.rglru_block(h, p["rglru"], cfg, rules, state=cache)
         x = x + a
-        h = L.norm(x, p["norm2"], cfg.norm)
+        h = L.norm(x, p["norm2"], cfg.norm, cfg.norm_eps)
         x = x + L.mlp_block(h, p["mlp"], cfg, rules)
     elif kind == "rwkv":
-        h = L.norm(x, p["norm1"], cfg.norm)
+        h = L.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
         a, tstate = W.rwkv_time_mix(h, p["time"], cfg, rules,
                                     state=None if cache is None else cache["time"],
                                     accounting=accounting)
         x = x + a
-        h = L.norm(x, p["norm2"], cfg.norm)
+        h = L.norm(x, p["norm2"], cfg.norm, cfg.norm_eps)
         c, cstate = W.rwkv_channel_mix(h, p["channel"], cfg, rules,
                                        state=None if cache is None else cache["channel"])
         x = x + c
@@ -199,7 +201,8 @@ def embed_inputs(params, batch: Dict[str, Any], cfg: ModelConfig, rules):
         # (dynamic-slice size > shard bug); the reshard is ~MBs and CSE'd.
         table = constrain(params["embed"], rules, ("vocab_rows", None))
         x = jnp.take(table, tokens, axis=0).astype(dtype)
-        x = x * float(np.sqrt(cfg.d_model))  # python float: weak type, keeps bf16
+        if cfg.embed_scale:
+            x = x * float(np.sqrt(cfg.d_model))  # python float: keeps bf16
         labels = jnp.concatenate(
             [tokens[:, 1:], jnp.zeros_like(tokens[:, :1])], axis=1)
         if cfg.frontend == "patches":
@@ -212,7 +215,7 @@ def embed_inputs(params, batch: Dict[str, Any], cfg: ModelConfig, rules):
 
 
 def unembed(params, x, cfg: ModelConfig, rules):
-    x = L.norm(x, params["final_norm"], cfg.norm)
+    x = L.norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:
         # the table at rest is (V→fsdp, d→tensor); for the logits matmul we
         # need V on the tensor axis (else GSPMD replicates the (B,S,V)
@@ -368,7 +371,8 @@ def decode_step(params, batch, cache, cfg: ModelConfig, rules: ShardingRules):
         x = jnp.einsum("bsd,de->bse", batch["frames"].astype(dtype), params["frame_proj"])
     else:
         x = jnp.take(params["embed"], batch["tokens"], axis=0).astype(dtype)
-        x = x * float(np.sqrt(cfg.d_model))  # python float: weak type, keeps bf16
+        if cfg.embed_scale:
+            x = x * float(np.sqrt(cfg.d_model))  # python float: keeps bf16
     pos = batch["pos"]                                    # (B, 1) int32 absolute
     media = batch.get("media")
     if media is not None:

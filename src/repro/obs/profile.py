@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Any, Callable, Dict, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 from jax.profiler import TraceAnnotation
@@ -79,15 +79,16 @@ def named_scope(name: str):
 
 
 def timed(name: str, fn: Callable, *args,
-          metrics=None, **kwargs) -> Tuple[Any, float]:
-    """Run ``fn(*args, **kwargs)`` inside ``span(name)``, blocking on the
-    result; returns ``(result, wall_seconds)`` and records the timing into
-    ``metrics.histogram(name)`` when given.
+          metrics=None, span_stats: Optional[Dict[str, Any]] = None,
+          **kwargs) -> Tuple[Any, float]:
+    """Run ``fn(*args, **kwargs)`` inside ``span(name, **span_stats)``,
+    blocking on the result; returns ``(result, wall_seconds)`` and records
+    the timing into ``metrics.histogram(name)`` when given.
 
     A failed device computation (an out-of-memory, a refused kernel)
     surfaces in the block and propagates: no timing is recorded for it."""
     t0 = time.perf_counter()
-    with span(name):
+    with span(name, **(span_stats or {})):
         out = jax.block_until_ready(fn(*args, **kwargs))
     dt = time.perf_counter() - t0
     if metrics is not None:

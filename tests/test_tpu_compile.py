@@ -35,11 +35,10 @@ KERNELS = ("agg_reduce", "agg_reduce_quant", "quantize_int8",
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -50,9 +49,15 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2[0])
 
 
 def _fits(compiled) -> int:
@@ -119,3 +124,29 @@ def test_client_stacked_chunk_step_compiles_for_v5e(one_chip):
                                vec(jnp.int32)).compile()
     # 128 stacked f32 deltas of 26.4 MB each are the program's argument
     assert _fits(compiled) >= rows * N * 4
+
+
+def test_olmo_1b_fsdp_step_fits_four_chips(v5e_2x2):
+    """OLMo-1B at its published widths, the gradient regime's step as
+    ``GradientBackend`` builds it: FSDP rules on a (4, 1) ("data",
+    "model") mesh, AdamW, 32 rows of 2048 tokens. Each chip's share fits
+    its 16 GiB (12.85 GiB), and the step gathers and reduces over the
+    mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from repro.launch import specs
+    from repro.launch.train import build_rules
+    from repro.models.config import ShapeConfig
+
+    cfg = configs.get("olmo_1b")
+    mesh = Mesh(np.array(v5e_2x2).reshape(4, 1), ("data", "model"))
+    rules = build_rules(mesh, "sfl")
+    shape = ShapeConfig("olmo1b_sfl_fsdp4", 2048, 32, "train")
+    fn, args, _ = specs.input_specs(cfg, shape, mesh, rules)
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert _fits(compiled) > 8 * 2**30
+    text = compiled.as_text()
+    assert "all-gather" in text and "all-reduce-scatter" in text
+
