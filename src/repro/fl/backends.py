@@ -64,7 +64,13 @@ def backend_wire_scale(backend) -> float:
 
 
 class ClientStackedBackend:
-    """Per-client model copies + H local steps (reproduces Fig. 2 on CPU)."""
+    """Per-client model copies + H local steps (reproduces Fig. 2 on CPU).
+
+    ``minibatch_fn(rng, client, steps, batch)`` returns one client's
+    ``(steps, batch, ...)`` minibatches as host (numpy) arrays: a round
+    stacks its padded clients' draws on the host and puts them on the
+    device in one transfer per leaf.
+    """
 
     def __init__(self, fl: FLConfig, strategy: Strategy, params,
                  clients, eval_batch, loss_fn: Callable,
@@ -125,6 +131,16 @@ class ClientStackedBackend:
             out.update(self._last_eval)
         return out
 
+    def _stack_minibatches(self, padded: np.ndarray,
+                           rng: np.random.Generator):
+        """The padded clients' draws, in order, stacked on the host into
+        one contiguous ``(n_padded, steps, batch, ...)`` array per leaf."""
+        fl = self.fl
+        return jax.tree.map(
+            lambda *xs: np.stack(xs),
+            *[self.minibatch_fn(rng, self.clients[c], fl.local_steps,
+                                fl.local_batch) for c in padded])
+
     def run_round(self, rnd: int, selected: np.ndarray, mask: np.ndarray,
                   rt: Dict[str, Any], rng: np.random.Generator
                   ) -> Dict[str, float]:
@@ -139,12 +155,11 @@ class ClientStackedBackend:
         padded = np.concatenate([active, np.full(pad, active[0])])
         w = np.concatenate([self.sample_counts[active], np.zeros(pad, np.float32)])
         with profile.span("round.minibatch", n_clients=len(padded)) as sp:
-            cb = jax.tree.map(
-                lambda *xs: jnp.stack(xs),
-                *[self.minibatch_fn(rng, self.clients[c], fl.local_steps,
-                                    fl.local_batch) for c in padded])
-            sp.set_metadata(h2d_bytes=sum(
-                x.nbytes for x in jax.tree.leaves(cb)))
+            host = self._stack_minibatches(padded, rng)
+            cb = jax.block_until_ready(jax.device_put(host))
+            sp.set_metadata(
+                h2d_bytes=sum(x.nbytes for x in jax.tree.leaves(cb)),
+                puts=len(jax.tree.leaves(host)))
         if self._chunk_program is None:
             # held across rounds: one trace, lowering and compile in all
             self._chunk_program = fedavg.chunk_program(

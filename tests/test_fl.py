@@ -236,6 +236,94 @@ def test_backend_builds_its_chunk_program_once():
     assert backend._chunk_program._cache_size() == 1
 
 
+def _stacked_loop():
+    """A small client-stacked RoundLoop: 10 selected in chunks of 4, so a
+    round pads its involved clients with weight-0 dummies up to 4k."""
+    cfg = configs.get("femnist_cnn").reduced()
+    params, _ = femnist_cnn.init_params(cfg, jax.random.PRNGKey(0))
+    pon = PonConfig(n_onus=4, clients_per_onu=5)
+    flc = FLConfig(n_onus=4, clients_per_onu=5, n_selected=10, local_steps=2,
+                   client_chunk=4, pon=pon)
+    clients, eval_set = femnist.generate(
+        femnist.FemnistConfig(n_clients=flc.n_clients, seed=11))
+    backend = fl.ClientStackedBackend(
+        flc, fl.make_strategy("sfl_two_step"), params, clients,
+        jax.tree.map(jnp.asarray, eval_set), _loss)
+    return fl.RoundLoop(fl.ExperimentConfig(fl=flc, n_rounds=3), backend)
+
+
+def _per_client_puts(self, padded, rng):
+    """The eager path the host-side stack replaced: each client's numpy
+    draws converted to the device on their own, then ``jnp.stack``."""
+    flc = self.fl
+    return jax.tree.map(
+        lambda *xs: jnp.stack(xs),
+        *[self.minibatch_fn(rng, self.clients[c], flc.local_steps,
+                            flc.local_batch) for c in padded])
+
+
+def test_round_minibatches_stack_on_host_bit_for_bit(monkeypatch):
+    """One host stack and one transfer per leaf hand the chunk program the
+    arrays the per-client eager path did: the same client batches, params
+    and rng stream after 3 padded rounds, bit for bit."""
+    train = fedavg.train_selected_clients
+
+    def run():
+        seen = []
+
+        def recording(params, cb, *a, **kw):
+            seen.append([np.asarray(x) for x in jax.tree.leaves(cb)])
+            return train(params, cb, *a, **kw)
+
+        monkeypatch.setattr(fedavg, "train_selected_clients", recording)
+        loop = _stacked_loop()
+        hist = loop.run()
+        return seen, hist, loop
+    seen, hist, loop = run()
+    with monkeypatch.context() as m:
+        m.setattr(fl.ClientStackedBackend, "_stack_minibatches",
+                  _per_client_puts)
+        old_seen, old_hist, old_loop = run()
+    assert len(seen) == len(old_seen) == 3
+    # ragged: the padding dummies ride in the stack too
+    assert any(b[0].shape[0] > r["involved"] for b, r in zip(seen, hist))
+    for new, old in zip(seen, old_seen):
+        for a, b in zip(new, old):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+    for a, b in zip(jax.tree.leaves(loop.backend.params),
+                    jax.tree.leaves(old_loop.backend.params)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert loop.rng.bit_generator.state == old_loop.rng.bit_generator.state
+    assert hist.column("eval_loss") == old_hist.column("eval_loss")
+
+
+def test_round_minibatch_span_puts_once_per_leaf():
+    """Each round's ``round.minibatch`` span says how many clients it
+    stacked, the bytes it sent (the padded clients' device bytes, as
+    before) and that it sent them in one transfer per leaf."""
+    from repro import obs
+    from repro.obs.context import Obs
+    from repro.obs.tracer import Tracer
+
+    loop = _stacked_loop()
+    backend, flc = loop.backend, loop.backend.fl
+    one = femnist.client_minibatches(np.random.default_rng(0),
+                                     backend.clients[0], flc.local_steps,
+                                     flc.local_batch)
+    per_client = sum(jnp.asarray(x).nbytes for x in jax.tree.leaves(one))
+    t = Tracer()
+    with obs.use(Obs(tracer=t)):
+        hist = loop.run()
+    spans = [s.args for s in t.spans if s.name == "round.minibatch"]
+    assert len(spans) == len(hist) == 3
+    for st, rec in zip(spans, hist):
+        assert st["n_clients"] % flc.client_chunk == 0
+        assert st["n_clients"] >= rec["involved"] > 0
+        assert st["h2d_bytes"] == st["n_clients"] * per_client
+        assert st["puts"] == len(jax.tree.leaves(one)) == 2
+
+
 def _transport_loop(n_selected=10, **exp_kw):
     pon = PonConfig(n_onus=4, clients_per_onu=5)
     flc = FLConfig(n_onus=4, clients_per_onu=5, n_selected=n_selected, pon=pon)
