@@ -1,6 +1,6 @@
 """Bench regression gate — compare two BENCH_*.json sweeps under policy.
 
-``python -m benchmarks.regress --baseline BENCH_PR6.json --candidate NEW.json``
+``python -m benchmarks.regress --baseline BENCH_PR10.json --candidate NEW.json``
 aligns the two artifacts bench by bench (rows keyed on each bench's
 natural axis — N, (dba, wavelengths, bg_load), (n_pons, mode), round,
 (policy, mode), kernel name) and classifies every metric delta:

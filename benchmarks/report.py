@@ -1,22 +1,16 @@
-"""Benchmark reporting helpers.
+"""Benchmark row emission.
 
-Two halves:
-
-  * :func:`emit_rows` / :func:`attach_schema` — the ONE stdout-CSV +
-    optional-JSON emission path shared by every bench main
-    (bench_accuracy / bench_dba / bench_hierarchy / bench_time_to_accuracy
-    used to copy-paste it). Every row is stamped with the uniform bench
-    schema tag plus the ``repro.obs`` metrics schema, so all
-    ``BENCH_*.json`` artifacts are mechanically comparable across PRs
-    (see ROADMAP: bench-snapshot convention).
-  * the EXPERIMENTS.md §Dry-run / §Roofline table builders from
-    results/dryrun/*.json (written by repro.launch.dryrun).
+:func:`emit_rows` / :func:`attach_schema` are the ONE stdout-CSV +
+optional-JSON emission path shared by every bench main (bench_accuracy /
+bench_dba / bench_hierarchy / bench_time_to_accuracy used to copy-paste
+it). Every row is stamped with the uniform bench schema tag plus the
+``repro.obs`` metrics schema, so all ``BENCH_*.json`` artifacts are
+mechanically comparable across PRs (see ROADMAP: bench-snapshot
+convention).
 """
 from __future__ import annotations
 
-import glob
 import json
-import os
 from typing import Dict, List, Optional, Sequence, Tuple
 
 BENCH_SCHEMA = "repro.bench/v1"
@@ -80,62 +74,3 @@ def assert_schema(rows_by_bench: Dict[str, List[Dict]]) -> None:
                     f"bench {bench!r} row {i} missing schema keys {missing} "
                     "— emit rows through benchmarks.report.emit_rows")
 
-
-def load(out_dir: str = "results/dryrun") -> List[Dict]:
-    rows = []
-    for p in sorted(glob.glob(os.path.join(out_dir, "*.json"))):
-        with open(p) as f:
-            rows.append(json.load(f))
-    return rows
-
-
-def fmt_bytes(b):
-    return f"{b/1e9:.2f}"
-
-
-def dryrun_table(rows: List[Dict], mesh: str = "single", tag: str = "") -> str:
-    rows = [r for r in rows if r["mesh"] == mesh and r.get("tag", "") == tag
-            and r["mode"] == "sfl"]
-    out = ["| arch | shape | compile s | args GB/dev | temp GB/dev | micro | opt |",
-           "|---|---|---|---|---|---|---|"]
-    for r in sorted(rows, key=lambda r: (r["arch"], r["shape"])):
-        m = r["memory"]
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {r['compile_s']} | "
-            f"{m['argument_gb']:.2f} | {m['temp_gb']:.2f} | "
-            f"{r.get('micro', 1)} | {r['opt']} |")
-    return "\n".join(out)
-
-
-def roofline_table(rows: List[Dict], mesh: str = "single", tag: str = "") -> str:
-    rows = [r for r in rows if r["mesh"] == mesh and r.get("tag", "") == tag
-            and r["mode"] == "sfl" and "roofline" in r]
-    out = ["| arch | shape | compute s | memory s (raw/fused) | collective s | "
-           "dominant | useful | frac | pod GB/dev | ici GB/dev |",
-           "|---|---|---|---|---|---|---|---|---|---|"]
-    for r in sorted(rows, key=lambda r: (r["arch"], r["shape"])):
-        rf = r["roofline"]
-        mf = rf.get("memory_fused_s", rf["memory_s"])
-        out.append(
-            f"| {r['arch']} | {r['shape']} | {rf['compute_s']:.3f} | "
-            f"{rf['memory_s']:.3f} / {mf:.3f} | {rf['collective_s']:.3f} | "
-            f"{rf.get('dominant_fused', rf['dominant'])} | "
-            f"{r.get('useful_ratio', 0):.2f} | "
-            f"{rf.get('roofline_frac_fused', rf.get('roofline_frac', 0)):.3f} | "
-            f"{rf['coll_pod_bytes']/1e9:.2f} | {rf['coll_ici_bytes']/1e9:.2f} |")
-    return "\n".join(out)
-
-
-def main():
-    rows = load()
-    print(f"{len(rows)} dry-run records")
-    for mesh in ("single", "multi"):
-        n = len([r for r in rows if r['mesh'] == mesh])
-        print(f"\n## {mesh}-pod ({n} cells)\n")
-        print(dryrun_table(rows, mesh))
-        print()
-        print(roofline_table(rows, mesh))
-
-
-if __name__ == "__main__":
-    main()

@@ -15,7 +15,6 @@
   pareto    — bandwidth–accuracy Pareto: {none,int8,int4,topk} wire
               compression × {sfl,hier_sfl,classical} (DESIGN.md §17)
   kernels   — ONU-AF / quantize / top-k micro-bench
-  report    — EXPERIMENTS tables from results/dryrun/*.json (if present)
 
 ``--json OUT.json`` additionally writes every bench's rows as
 machine-readable JSON ({bench: [row, ...]}) so the perf/accuracy
@@ -33,7 +32,7 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="upstream|involved|accuracy|dba|hierarchy|scale|"
-                         "time_to_accuracy|pareto|kernels|report")
+                         "time_to_accuracy|pareto|kernels")
     ap.add_argument("--full", action="store_true",
                     help="accuracy bench with the full LEAF CNN (slow)")
     ap.add_argument("--rounds", type=int, default=None,
@@ -91,9 +90,6 @@ def main() -> None:
     names = [args.only] if args.only else list(benches)
     collected = {}
     for name in names:
-        if name == "report":
-            report.main()
-            continue
         t0 = time.time()
         print(f"\n=== {name} ===")
         rows = benches[name]()

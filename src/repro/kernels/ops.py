@@ -2,8 +2,9 @@
 
 On TPU the Pallas kernels run compiled (interpret=False); on a CPU host
 they execute in interpret mode when explicitly requested (tests) and
-otherwise fall back to the jnp reference — which is also what the GSPMD
-dry-run lowers, since Mosaic kernels cannot lower for the CPU backend.
+otherwise fall back to the jnp reference — which is also what a GSPMD
+lowering on the CPU sees, since Mosaic kernels cannot lower for the CPU
+backend.
 :func:`dispatch_mode` is the single choke point, and what a benchmark row
 stamps so that a reference timing is never read as a kernel time.
 """

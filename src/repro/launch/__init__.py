@@ -1,5 +1,3 @@
-# NOTE: do not import dryrun here — it sets XLA_FLAGS at import time and is
-# only meant to be executed as a __main__ launcher.
-from repro.launch import hw, mesh
+from repro.launch import mesh
 
-__all__ = ["hw", "mesh"]
+__all__ = ["mesh"]

@@ -1,11 +1,9 @@
-"""Production meshes.
+"""Device meshes.
 
-``make_production_mesh`` is a FUNCTION (never module-level) so importing
-this module touches no jax device state. The single-pod mesh is 16x16 = 256
-chips ("data", "model"); the multi-pod mesh is 2x16x16 = 512 chips
-("pod", "data", "model"). The dry-run launcher sets
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import;
-tests and benches see the 1 real CPU device.
+``make_test_mesh`` is a FUNCTION (never module-level) so importing this
+module touches no jax device state: it builds a mesh over the first
+``prod(shape)`` devices JAX sees, the chips on a TPU host or the host
+devices on the CPU (``--xla_force_host_platform_device_count``).
 """
 from __future__ import annotations
 
@@ -14,38 +12,11 @@ from typing import Tuple
 import numpy as np
 
 
-def make_production_mesh(*, multi_pod: bool = False):
-    import jax
-    from jax.sharding import Mesh
-    from jax.experimental import mesh_utils
-
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devs = jax.devices()
-    if len(devs) < n:
-        raise RuntimeError(
-            f"need {n} devices, have {len(devs)} — run under launch/dryrun.py "
-            "(it sets xla_force_host_platform_device_count=512)")
-    arr = mesh_utils.create_device_mesh(shape, devices=devs[:n])
-    return Mesh(arr, axes)
-
-
 def make_test_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Small mesh over however many host devices exist (tests)."""
+    """A mesh of ``shape`` named ``axes`` over the first devices."""
     import jax
     from jax.sharding import Mesh
 
     n = int(np.prod(shape))
     devs = np.array(jax.devices()[:n]).reshape(shape)
     return Mesh(devs, axes)
-
-
-def device_coords(mesh) -> dict:
-    """device id -> mesh coordinate tuple (for the collective parser)."""
-    out = {}
-    it = np.nditer(np.empty(mesh.devices.shape), flags=["multi_index"])
-    for _ in it:
-        coord = it.multi_index
-        out[mesh.devices[coord].id] = coord
-    return out

@@ -1,7 +1,7 @@
 """Batched serving driver: prefill + token-by-token decode.
 
-CPU-runnable with --smoke; the same step functions lower on the production
-meshes in the dry-run (decode_32k / long_500k cells).
+CPU-runnable with --smoke; ``launch.specs.input_specs`` lowers the same
+step functions for prefill and decode shapes on any mesh.
 
   PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-3b --smoke \
       --batch 4 --prompt-len 64 --gen 32

@@ -1,11 +1,11 @@
-"""Abstract input specs + step functions for every (arch × shape) cell.
+"""Abstract input specs + step functions for every (arch × shape) pair.
 
 ``input_specs(cfg, shape, mesh, rules)`` returns ShapeDtypeStructs with
 shardings attached (weak-type-correct, shardable, zero allocation) for the
-cell's step function:
-  train_4k     -> train_step(state, batch)
-  prefill_32k  -> prefill_step(params, batch)
-  decode_*     -> serve_step(params, batch, cache)   (one new token)
+step function of the shape's kind:
+  train    -> train_step(state, batch)
+  prefill  -> prefill_step(params, batch)
+  decode   -> serve_step(params, batch, cache)   (one new token)
 
 The batch always carries per-client FL metadata: ``client_weight`` (k_ij ·
 participation-mask per batch row) — the SFL aggregation weights, folded
@@ -324,7 +324,8 @@ def make_serve_step(cfg: ModelConfig, rules: ShardingRules):
 def input_specs(cfg: ModelConfig, shp: ShapeConfig, mesh: Mesh,
                 rules: ShardingRules, opt_name: str = "adamw",
                 microbatches: int = 1, transport: str = "gspmd"):
-    """Everything the dry-run needs for one cell: (fn, args, out_shardings)."""
+    """The step for one (arch × shape) pair and its abstract arguments:
+    (fn, args, out_shardings)."""
     params_abs, params_shard = param_shardings(cfg, mesh, rules)
     if shp.kind == "train":
         batch = batch_struct(cfg, shp, mesh, rules)

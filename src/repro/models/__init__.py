@@ -1,13 +1,4 @@
-from repro.models.config import (
-    ModelConfig,
-    ShapeConfig,
-    ALL_SHAPES,
-    TRAIN_4K,
-    PREFILL_32K,
-    DECODE_32K,
-    LONG_500K,
-    shape_by_name,
-)
+from repro.models.config import ModelConfig, ShapeConfig
 from repro.models import transformer, femnist_cnn
 
 
@@ -17,14 +8,13 @@ def init_params(cfg, key=None, abstract=False, tp: int = 16):
     return transformer.init_params(cfg, key, abstract, tp)
 
 
-def loss_fn(params, batch, cfg, rules, **kw):
+def loss_fn(params, batch, cfg, rules):
     if cfg.family == "cnn":
         return femnist_cnn.loss_fn(params, batch, cfg, rules)
-    return transformer.loss_fn(params, batch, cfg, rules, **kw)
+    return transformer.loss_fn(params, batch, cfg, rules)
 
 
 __all__ = [
-    "ModelConfig", "ShapeConfig", "ALL_SHAPES", "TRAIN_4K", "PREFILL_32K",
-    "DECODE_32K", "LONG_500K", "shape_by_name", "init_params", "loss_fn",
-    "transformer", "femnist_cnn",
+    "ModelConfig", "ShapeConfig", "init_params", "loss_fn", "transformer",
+    "femnist_cnn",
 ]

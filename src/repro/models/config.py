@@ -62,8 +62,6 @@ class ModelConfig:
     q_chunk: int = 512             # attention query-block size
     loss_chunks: int = 4           # sequence chunks for the softmax-xent
     scan_layers: bool = True       # scan over layer units (False = unroll)
-    attn_accounting: bool = False  # unrolled static-causal attention (exact
-                                   # FLOPs; used by roofline segment lowering)
     rwkv_chunk: int = 128
     tie_embeddings: bool = False
     tp_pad_heads: bool = True
@@ -172,22 +170,9 @@ class ModelConfig:
 
 @dataclasses.dataclass(frozen=True)
 class ShapeConfig:
-    """One (input-shape) cell of the assignment."""
+    """One input shape: sequence length, global batch and step kind."""
     name: str
     seq_len: int
     global_batch: int
     kind: str                      # train|prefill|decode
 
-
-TRAIN_4K = ShapeConfig("train_4k", 4096, 256, "train")
-PREFILL_32K = ShapeConfig("prefill_32k", 32768, 32, "prefill")
-DECODE_32K = ShapeConfig("decode_32k", 32768, 128, "decode")
-LONG_500K = ShapeConfig("long_500k", 524288, 1, "decode")
-ALL_SHAPES = (TRAIN_4K, PREFILL_32K, DECODE_32K, LONG_500K)
-
-
-def shape_by_name(name: str) -> ShapeConfig:
-    for s in ALL_SHAPES:
-        if s.name == name:
-            return s
-    raise KeyError(name)

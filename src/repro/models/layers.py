@@ -2,15 +2,10 @@
 sliding-window / cross), dense MLPs.
 
 All functions are pure; parameters come in as dicts built by ParamBuilder.
-Attention has two execution modes sharing the same math:
-
-* ``accounting=False`` (default): ``lax.scan`` over query blocks, each block
-  attends to the full (masked) KV — compact HLO for the scanned-over-layers
-  full program.
-* ``accounting=True``: a static python loop over query blocks where block i
-  only touches KV[0 : (i+1)*q_chunk] (static slice). No while loops, no
-  masked-away FLOPs — this is what the roofline segment lowering uses, so
-  HLO FLOP counts are exact-causal.
+Training attention is a ``lax.scan`` over query blocks of ``q_chunk`` rows,
+each block attending to the full KV under a causal (and, with a window,
+sliding) mask: compact HLO inside the scanned-over-layers program, at the
+cost of the masked-away FLOPs above the diagonal.
 """
 from __future__ import annotations
 
@@ -152,7 +147,7 @@ def _attend_block(q_blk, k_ctx, v_ctx, mask, scale, softcap=0.0):
     return jnp.einsum("bhqs,bshk->bqhk", probs.astype(v_ctx.dtype), v_ctx)
 
 
-def causal_attention(q, k, v, cfg, rules, *, window: int = 0, accounting: bool = False):
+def causal_attention(q, k, v, cfg, rules, *, window: int = 0):
     """Chunked causal (optionally sliding-window) attention.
 
     q (B,S,Hp,hd); k,v (B,S,KV,hd). Returns (B,S,Hp,hd).
@@ -169,34 +164,19 @@ def causal_attention(q, k, v, cfg, rules, *, window: int = 0, accounting: bool =
     assert S % C == 0, (S, C)
     span = jnp.arange(C)
 
-    if accounting:
-        outs = []
-        for i in range(n_blk):
-            qi = q[:, i * C:(i + 1) * C]
-            lo = 0 if window == 0 else max(0, (i + 1) * C - C - window + 1)
-            hi = (i + 1) * C
-            kc, vc = kf[:, lo:hi], vf[:, lo:hi]
-            qpos = i * C + span
-            kpos = lo + jnp.arange(hi - lo)
-            m = kpos[None, :] <= qpos[:, None]
-            if window:
-                m &= kpos[None, :] > qpos[:, None] - window
-            outs.append(_attend_block(qi, kc, vc, m[None, None], scale, cfg.logit_softcap))
-        o = jnp.concatenate(outs, axis=1)
-    else:
-        qr = q.reshape(B, n_blk, C, Hp, hd).transpose(1, 0, 2, 3, 4)
-        kpos = jnp.arange(S)
+    qr = q.reshape(B, n_blk, C, Hp, hd).transpose(1, 0, 2, 3, 4)
+    kpos = jnp.arange(S)
 
-        def body(_, blk):
-            i, qi = blk
-            qpos = i * C + span
-            m = kpos[None, :] <= qpos[:, None]
-            if window:
-                m &= kpos[None, :] > qpos[:, None] - window
-            return 0, _attend_block(qi, kf, vf, m[None, None], scale, cfg.logit_softcap)
+    def body(_, blk):
+        i, qi = blk
+        qpos = i * C + span
+        m = kpos[None, :] <= qpos[:, None]
+        if window:
+            m &= kpos[None, :] > qpos[:, None] - window
+        return 0, _attend_block(qi, kf, vf, m[None, None], scale, cfg.logit_softcap)
 
-        _, o = jax.lax.scan(body, 0, (jnp.arange(n_blk), qr))
-        o = o.transpose(1, 0, 2, 3, 4).reshape(B, S, Hp, hd)
+    _, o = jax.lax.scan(body, 0, (jnp.arange(n_blk), qr))
+    o = o.transpose(1, 0, 2, 3, 4).reshape(B, S, Hp, hd)
 
     hm = _head_mask(Hp, H, o.dtype)
     if hm is not None:
@@ -204,8 +184,7 @@ def causal_attention(q, k, v, cfg, rules, *, window: int = 0, accounting: bool =
     return o
 
 
-def self_attention(x, p, cfg, rules, positions, *, window: int = 0,
-                   accounting: bool = False, cache=None):
+def self_attention(x, p, cfg, rules, positions, *, window: int = 0, cache=None):
     """Full self-attention sublayer (projections + rope + attend + out-proj).
 
     cache: None for train/prefill-without-cache; dict(k, v, pos) for decode.
@@ -218,7 +197,7 @@ def self_attention(x, p, cfg, rules, positions, *, window: int = 0,
     k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is None:
-        o = causal_attention(q, k, v, cfg, rules, window=window, accounting=accounting)
+        o = causal_attention(q, k, v, cfg, rules, window=window)
         new_kv = (k, v)
     else:
         o, new_kv = _decode_attention(q, k, v, cache, cfg, window)

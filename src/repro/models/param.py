@@ -3,8 +3,8 @@ structure so init code cannot drift from sharding specs.
 
 Params are plain nested dicts of jnp arrays; a parallel dict of logical-axis
 tuples is built by the same calls. ``abstract=True`` builds
-ShapeDtypeStructs instead of allocating (used by the dry-run: no host RAM is
-spent on 480B-parameter trees).
+ShapeDtypeStructs instead of allocating (used by ``launch.specs`` to lower
+steps without spending host RAM on the parameter tree).
 """
 from __future__ import annotations
 

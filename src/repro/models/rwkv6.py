@@ -7,8 +7,7 @@ with w_t = exp(-exp(ww_t)) data-dependent (LoRA on the shifted input).
 
 Train/prefill use the chunkwise-parallel form (chunk size cfg.rwkv_chunk):
 within-chunk pair interactions use the numerically-safe decay-difference
-tensor (all exponents ≤ 0), cross-chunk state flows through a scan (or a
-python loop in accounting mode so HLO FLOPs are fully counted).
+tensor (all exponents ≤ 0), cross-chunk state flows through a scan.
 
 The Pallas kernel in repro/kernels/rwkv6_scan.py implements the same
 chunk body with VMEM tiling; repro/kernels/ref.py's oracle is the exact
@@ -121,7 +120,7 @@ def _chunk_body(r, k, v, logw, u, S_in, head_mask):
     return o, S_out
 
 
-def rwkv_time_mix(x, p, cfg, rules: ShardingRules, state=None, accounting=False):
+def rwkv_time_mix(x, p, cfg, rules: ShardingRules, state=None):
     """Time-mix sublayer. state: None (train) or
     {'S': (B,H,hd,hd) f32, 'shift': (B,d)} for decode/chunked prefill."""
     B, S, d = x.shape
@@ -164,7 +163,7 @@ def rwkv_time_mix(x, p, cfg, rules: ShardingRules, state=None, accounting=False)
         return t.reshape(B, n_chunks, W, Hp, hd)
 
     rc, kc, vc, wc = split(r), split(k), split(v), split(logw)
-    if accounting or n_chunks == 1:
+    if n_chunks == 1:
         outs, St = [], S0
         for i in range(n_chunks):
             o, St = _chunk_body(rc[:, i], kc[:, i], vc[:, i], wc[:, i], u, St, head_mask)

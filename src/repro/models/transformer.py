@@ -17,7 +17,7 @@ the same code lowers on any mesh.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +64,7 @@ def _sublayer_params(pb: ParamBuilder, cfg: ModelConfig, kind: str, tp: int):
 def init_params(cfg: ModelConfig, key=None, abstract: bool = False, tp: int = 16):
     """Returns (params, logical_axes) — both nested dicts of identical shape.
 
-    abstract=True builds ShapeDtypeStructs (dry-run: no allocation).
+    abstract=True builds ShapeDtypeStructs (no allocation).
     """
     dtype = jnp.dtype(cfg.dtype)
     if key is None and not abstract:
@@ -124,7 +124,7 @@ def init_params(cfg: ModelConfig, key=None, abstract: bool = False, tp: int = 16
 # ---------------------------------------------------------------------------
 
 def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
-                    cache=None, media=None, accounting=False):
+                    cache=None, media=None):
     """Returns (x, new_cache). cache=None in training."""
     aux = 0.0
     if kind in ("attn", "cross"):
@@ -132,8 +132,7 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
         if kind == "attn":
             window = cfg.window
             a, new_cache = L.self_attention(
-                h, p["attn"], cfg, rules, positions, window=window,
-                accounting=accounting, cache=cache)
+                h, p["attn"], cfg, rules, positions, window=window, cache=cache)
         else:
             a = L.cross_attention(h, p["attn"], cfg, rules, media)
             new_cache = cache if cache is not None else None
@@ -155,8 +154,7 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
     elif kind == "rwkv":
         h = L.norm(x, p["norm1"], cfg.norm, cfg.norm_eps)
         a, tstate = W.rwkv_time_mix(h, p["time"], cfg, rules,
-                                    state=None if cache is None else cache["time"],
-                                    accounting=accounting)
+                                    state=None if cache is None else cache["time"])
         x = x + a
         h = L.norm(x, p["norm2"], cfg.norm, cfg.norm_eps)
         c, cstate = W.rwkv_channel_mix(h, p["channel"], cfg, rules,
@@ -168,15 +166,14 @@ def _apply_sublayer(x, p, cfg: ModelConfig, rules, kind: str, positions,
     return x, new_cache, aux
 
 
-def _apply_unit(x, unit_p, cfg, rules, positions, unit_cache=None, media=None,
-                accounting=False):
+def _apply_unit(x, unit_p, cfg, rules, positions, unit_cache=None, media=None):
     new_cache = {}
     aux_total = 0.0
     for i, kind in enumerate(cfg.block_pattern):
         key = f"{i}_{kind}"
         c = None if unit_cache is None else unit_cache.get(key)
         x, nc, aux = _apply_sublayer(x, unit_p[key], cfg, rules, kind, positions,
-                                     cache=c, media=media, accounting=accounting)
+                                     cache=c, media=media)
         new_cache[key] = nc
         aux_total = aux_total + aux
     return x, new_cache, aux_total
@@ -252,10 +249,9 @@ def _xent(logits, labels, mask):
 # forward passes
 # ---------------------------------------------------------------------------
 
-def _unit_step_fn(cfg, rules, media, accounting):
+def _unit_step_fn(cfg, rules, media):
     def step(x, unit_p, positions):
-        y, _, aux = _apply_unit(x, unit_p, cfg, rules, positions, media=media,
-                                accounting=accounting)
+        y, _, aux = _apply_unit(x, unit_p, cfg, rules, positions, media=media)
         return y, aux
     if cfg.remat == "full":
         step = jax.checkpoint(step, policy=jax.checkpoint_policies.nothing_saveable)
@@ -265,13 +261,10 @@ def _unit_step_fn(cfg, rules, media, accounting):
     return step
 
 
-def forward(params, batch, cfg: ModelConfig, rules: ShardingRules,
-            accounting: Optional[bool] = None):
+def forward(params, batch, cfg: ModelConfig, rules: ShardingRules):
     """Full training-style forward: returns (pre-head activations, labels, aux)."""
-    if accounting is None:
-        accounting = cfg.attn_accounting
     x, media, labels, positions = embed_inputs(params, batch, cfg, rules)
-    step = _unit_step_fn(cfg, rules, media, accounting)
+    step = _unit_step_fn(cfg, rules, media)
 
     aux_total = 0.0
     if cfg.scan_layers and cfg.n_units > 1:
@@ -287,16 +280,15 @@ def forward(params, batch, cfg: ModelConfig, rules: ShardingRules,
             aux_total = aux_total + aux
     for i, kind in enumerate(cfg.tail_pattern):
         x, _, aux = _apply_sublayer(x, params["tail"][f"{i}_{kind}"], cfg, rules,
-                                    kind, positions, media=media, accounting=accounting)
+                                    kind, positions, media=media)
         aux_total = aux_total + aux
     return x, labels, aux_total
 
 
-def loss_fn(params, batch, cfg: ModelConfig, rules: ShardingRules,
-            accounting: Optional[bool] = None):
+def loss_fn(params, batch, cfg: ModelConfig, rules: ShardingRules):
     """Scalar mean loss (+ metrics dict). Head is applied in sequence chunks
     so the (B, S, vocab) logits tensor never fully materializes."""
-    x, labels, aux = forward(params, batch, cfg, rules, accounting)
+    x, labels, aux = forward(params, batch, cfg, rules)
     B, S, _ = x.shape
     mask = batch.get("loss_mask")
     if mask is None:

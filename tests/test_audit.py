@@ -473,26 +473,22 @@ def test_bench_pr7_baseline_matches_current_schema():
                           "kernels", "accuracy", "time_to_accuracy"}
 
 
-# ----------------------------------------------------------- freeze_tables
+# ------------------------------------------------------------------ report
 
-def test_freeze_tables_emits_schema_stamped_rows(tmp_path, monkeypatch):
-    (tmp_path / "results" / "dryrun").mkdir(parents=True)
-    cell = {"arch": "qwen2-0.5b", "shape": "smoke", "mesh": "single",
-            "mode": "sfl", "compile_s": 1.2,
-            "memory": {"argument_gb": 0.5, "temp_gb": 0.25},
-            "roofline": {"compute_s": 0.1, "memory_s": 0.2,
-                         "collective_s": 0.05, "dominant": "memory",
-                         "coll_pod_bytes": 1e9, "coll_ici_bytes": 0.0},
-            "useful_ratio": 0.8}
-    with open(tmp_path / "results" / "dryrun" / "cell.json", "w") as f:
-        json.dump(cell, f)
-    monkeypatch.chdir(tmp_path)
-    from benchmarks import freeze_tables, report
-    rows = freeze_tables.main(["--json", str(tmp_path / "frozen.json")])
-    assert len(rows) == 1
-    report.assert_schema({"freeze_tables": rows})
-    assert rows[0]["bench_schema"] == report.BENCH_SCHEMA
-    assert rows[0]["arch"] == "qwen2-0.5b"
-    assert (tmp_path / "results" / "tables.md").exists()
-    frozen = json.load(open(tmp_path / "frozen.json"))
-    assert list(frozen) == ["freeze_tables"]
+def test_emit_rows_stamps_schema_and_writes_json(tmp_path, capsys):
+    from benchmarks import report
+    from repro.obs import SCHEMA as OBS_SCHEMA
+    rows = [{"N": 32, "mbits": 211.328, "note": None},
+            {"N": 64, "mbits": 422.656, "note": "x"}]
+    out = tmp_path / "rows.json"
+    stamped = report.emit_rows(rows, "demo", [("N", ""), ("mbits", ".1f"),
+                                              ("note", "")],
+                               header="=== demo ===", json_out=str(out))
+    assert "bench" not in rows[0]                 # the caller's rows untouched
+    report.assert_schema({"demo": stamped})
+    assert all(r["bench"] == "demo" and r["bench_schema"] == report.BENCH_SCHEMA
+               and r["obs_schema"] == OBS_SCHEMA for r in stamped)
+    lines = capsys.readouterr().out.splitlines()
+    assert lines[:4] == ["=== demo ===", "N,mbits,note", "32,211.3,",
+                         "64,422.7,x"]
+    assert json.load(open(out)) == {"demo": stamped}

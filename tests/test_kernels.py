@@ -235,7 +235,7 @@ def test_flash_matches_model_chunked_attention():
     v = jax.random.normal(ks[2], (B, S, 2, 16), jnp.float32)
     from repro.common.sharding import ShardingRules
     rules = ShardingRules(batch=None, fsdp=None, tensor=None, expert=None)
-    model_out = causal_attention(q, k, v, cfg, rules, accounting=True)
+    model_out = causal_attention(q, k, v, cfg, rules)
     kern_out = flash_attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                                v.transpose(0, 2, 1, 3), causal=True,
                                bq=64, bk=64, interpret=True)

@@ -117,14 +117,41 @@ def test_head_padding_exactness():
     assert np.abs(gq[:, :, 6:, :]).max() == 0.0
 
 
-def test_accounting_attention_matches_scan_attention():
-    cfg = configs.get_smoke("deepseek_coder_33b")
-    params, _ = init_params(cfg, KEY)
-    batch = _batch_for(cfg, 2, 64)
-    xa, _, _ = transformer.forward(params, batch, cfg, RULES, accounting=True)
-    xs, _, _ = transformer.forward(params, batch, cfg, RULES, accounting=False)
-    np.testing.assert_allclose(np.asarray(xa, np.float32),
-                               np.asarray(xs, np.float32), rtol=2e-2, atol=2e-2)
+def _dense_causal_attention(q, k, v, window):
+    """Plain masked-softmax attention, every query against every key."""
+    B, S, H, hd = q.shape
+    kv = np.arange(H) // (H // k.shape[2])        # query head -> its KV head
+    logits = np.einsum("bqhd,bshd->bhqs", q, k[:, :, kv]) / np.sqrt(hd)
+    pos = np.arange(S)
+    mask = pos[None, :] <= pos[:, None]
+    if window:
+        mask &= pos[None, :] > pos[:, None] - window
+    logits = np.where(mask, logits, -np.inf)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    return np.einsum("bhqs,bshd->bqhd", probs, v[:, :, kv])
+
+
+@pytest.mark.parametrize("window", [0, 6])
+def test_causal_attention_matches_dense_reference(window):
+    """The query-block scan (4 blocks of q_chunk=16, GQA 4:2) against plain
+    masked-softmax attention; window 6 < q_chunk slides across block
+    edges."""
+    from repro.models.config import ModelConfig
+    from repro.models.layers import causal_attention
+    cfg = ModelConfig(name="t", family="dense", n_layers=1, d_model=64,
+                      n_heads=4, n_kv_heads=2, d_ff=128, vocab_size=64,
+                      head_dim=16, q_chunk=16)
+    ks = jax.random.split(KEY, 3)
+    B, S = 2, 64
+    q = jax.random.normal(ks[0], (B, S, 4, 16), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, 2, 16), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, 2, 16), jnp.float32)
+    got = causal_attention(q, k, v, cfg, RULES, window=window)
+    want = _dense_causal_attention(*(np.asarray(t, np.float64)
+                                     for t in (q, k, v)), window)
+    np.testing.assert_allclose(np.asarray(got, np.float64), want,
+                               rtol=1e-5, atol=1e-5)
 
 
 def test_param_counts_match_analytic():
