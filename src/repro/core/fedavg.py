@@ -1,7 +1,9 @@
 """Faithful FedAvg / SFL round engine (client-stacked, H local steps).
 
 This is the paper-scale regime: every selected client holds its own model
-copy, runs H local SGD steps on its own (non-IID) data, and the round ends
+copy (a large dense weight as the global weight plus the client's step
+history, ``core/factored.py``), runs H local SGD steps on its own
+(non-IID) data, and the round ends
 with the two-step aggregation (``segment_aggregate``) under the PON
 simulator's participation mask. Reproduces Fig. 2 end-to-end on CPU.
 
@@ -16,13 +18,14 @@ its ``sfl_two_step``/``classical`` strategies are bit-for-bit the
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import aggregation
+from repro.core import aggregation, factored
 from repro.obs import profile
 from repro.pon import PonConfig, round_times
 
@@ -101,13 +104,61 @@ def local_sgd(params, batches: Dict[str, jax.Array], loss_fn: Callable,
 
     batches: dict of arrays with leading (steps, batch, ...) axes.
     """
-    def step(p, batch):
-        (l, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
-        p = jax.tree.map(lambda w, gw: (w.astype(jnp.float32) - lr * gw).astype(w.dtype), p, g)
-        return p, l
-    p, losses = jax.lax.scan(step, params,
-                             jax.tree.map(lambda x: x[:steps], batches))
-    return p, jnp.mean(losses)
+    p, moved, loss = _sgd(params, batches, loss_fn, lr, steps)
+    leaves, treedef = jax.tree.flatten(p)
+    return treedef.unflatten([w + moved[i] if i in moved else w
+                              for i, w in enumerate(leaves)]), loss
+
+
+def _sgd(params, batches, loss_fn: Callable, lr: float, steps: int):
+    """H SGD steps → (params, {leaf index: its change}, mean loss).
+
+    A weight that ``factored.plan`` picks is carried as the global weight
+    plus the steps' input and output-gradient rows, never as this client's
+    own copy; it comes back unchanged in the params and its change, the
+    rows' product, in the dict. Every other leaf takes the dense update.
+    """
+    batches = jax.tree.map(lambda x: x[:steps], batches)
+    leaves, treedef = jax.tree.flatten(params)
+    taps = factored.plan(loss_fn, leaves, treedef, batches)
+    update = lambda w, gw: (w.astype(jnp.float32) - lr * gw).astype(w.dtype)
+    n = jax.tree.leaves(batches)[0].shape[0]
+
+    def step(carry, inp):
+        p, rows = carry
+        batch, t = inp
+
+        def loss_of(p, tap):
+            full = [factored.History(leaves[i], *rows[i], *tap[i])
+                    if i in taps else w for i, w in enumerate(p)]
+            return loss_fn(treedef.unflatten(full), batch)
+
+        def append(h, v):   # step t's rows of a (steps · rows, d) history
+            return jax.lax.dynamic_update_slice_in_dim(
+                h, v.reshape(-1, h.shape[1]).astype(h.dtype),
+                t * (h.shape[0] // n), 0)
+
+        tap = {i: (jnp.zeros(x.shape, x.dtype), jnp.zeros(y.shape, y.dtype))
+               for i, (x, y) in taps.items()}
+        (l, _), (g, got) = jax.value_and_grad(
+            loss_of, argnums=(0, 1), has_aux=True)(p, tap)
+        p = jax.tree.map(update, p, g)
+        rows = {i: (append(xs, got[i][0]), append(gs, -lr * got[i][1]))
+                for i, (xs, gs) in rows.items()}
+        return (p, rows), l
+
+    rows0 = {}
+    for i, (x, _) in taps.items():
+        k = n * math.prod(x.shape[:-1])
+        d_in, d_out = leaves[i].shape
+        rows0[i] = (jnp.zeros((k, d_in), jnp.float32),
+                    jnp.zeros((k, d_out), jnp.float32))
+    p0 = [None if i in taps else w for i, w in enumerate(leaves)]
+    (p, rows), losses = jax.lax.scan(step, (p0, rows0),
+                                     (batches, jnp.arange(n)))
+    p = treedef.unflatten([leaves[i] if i in taps else w
+                           for i, w in enumerate(p)])
+    return p, {i: xs.T @ gs for i, (xs, gs) in rows.items()}, jnp.mean(losses)
 
 
 def local_sgd_prox(params, batches: Dict[str, jax.Array], loss_fn: Callable,
@@ -134,9 +185,13 @@ def local_sgd_prox(params, batches: Dict[str, jax.Array], loss_fn: Callable,
 def default_local_update(global_params, batches, loss_fn: Callable,
                          fl: FLConfig):
     """One client's FedAvg local update: H SGD steps → weight delta."""
-    p, l = local_sgd(global_params, batches, loss_fn, fl.local_lr, fl.local_steps)
-    delta = jax.tree.map(lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-                         p, global_params)
+    p, moved, l = _sgd(global_params, batches, loss_fn, fl.local_lr,
+                       fl.local_steps)
+    leaves, treedef = jax.tree.flatten(p)
+    delta = treedef.unflatten([
+        moved[i] if i in moved
+        else a.astype(jnp.float32) - b.astype(jnp.float32)
+        for i, (a, b) in enumerate(zip(leaves, jax.tree.leaves(global_params)))])
     return delta, l
 
 
@@ -150,14 +205,20 @@ def chunk_program(loss_fn: Callable, fl: FLConfig,
     one program serves every round; ``loss_fn``, ``fl`` and
     ``local_update`` are static. Hold it across calls: a fresh ``jax.jit``
     traces, lowers and compiles (or reads the persistent cache) again.
+    ``program.factored_params``, set when it traces, counts the parameters
+    per client that its local SGD carries factored (``core/factored.py``).
     """
     if local_update is None:
         local_update = default_local_update
 
     def local_train_chunk(global_params, batches):
-        return local_update(global_params, batches, loss_fn, fl)
+        with factored.counting() as n:
+            out = local_update(global_params, batches, loss_fn, fl)
+        program.factored_params = n[0]
+        return out
 
-    return jax.jit(jax.vmap(local_train_chunk, in_axes=(None, 0)))
+    program = jax.jit(jax.vmap(local_train_chunk, in_axes=(None, 0)))
+    return program
 
 
 def train_selected_clients(global_params, client_batches, loss_fn: Callable,
@@ -181,9 +242,10 @@ def train_selected_clients(global_params, client_batches, loss_fn: Callable,
     deltas, losses = [], []
     for lo in range(0, n_sel, chunk):
         with profile.span("train.chunk", chunk=lo // chunk,
-                          rows=min(chunk, n_sel - lo)):
+                          rows=min(chunk, n_sel - lo)) as sp:
             cb = jax.tree.map(lambda x: x[lo:lo + chunk], client_batches)
             d, l = program(global_params, cb)
+            sp.set_metadata(factored_params=program.factored_params)
         deltas.append(d)
         losses.append(l)
     deltas = jax.tree.map(lambda *xs: jnp.concatenate(xs, 0), *deltas)

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.config import ModelConfig
+from repro.models.layers import dense
 from repro.models.param import ParamBuilder
 
 
@@ -62,8 +63,8 @@ def apply(params, images):
     x = jax.nn.relu(_conv(x, params["conv2_w"], params["conv2_b"]))
     x = _maxpool(x)
     x = x.reshape(x.shape[0], -1)
-    x = jax.nn.relu(x @ params["fc1_w"] + params["fc1_b"])
-    return x @ params["fc2_w"] + params["fc2_b"]
+    x = jax.nn.relu(dense(x, params["fc1_w"]) + params["fc1_b"])
+    return dense(x, params["fc2_w"]) + params["fc2_b"]
 
 
 def loss_fn(params, batch, cfg=None, rules=None):

@@ -1,5 +1,5 @@
 """Core neural layers: norms, RoPE, chunked causal attention (GQA / MQA /
-sliding-window / cross), dense MLPs.
+sliding-window / cross), dense layers and MLPs.
 
 All functions are pure; parameters come in as dicts built by ParamBuilder.
 Training attention is a ``lax.scan`` over query blocks of ``q_chunk`` rows,
@@ -266,6 +266,15 @@ def cross_attention(x, p, cfg, rules, media_kv):
 # ---------------------------------------------------------------------------
 # MLP
 # ---------------------------------------------------------------------------
+
+def dense(x, w):
+    """``x @ w``. A weight used only here may arrive in another form that
+    provides ``rmatmul(x)``: local SGD carries a large one that way, as
+    the global weight plus the client's step history (``core/factored``).
+    """
+    rmatmul = getattr(w, "rmatmul", None)
+    return x @ w if rmatmul is None else rmatmul(x)
+
 
 def mlp_params(pb, cfg, name: str = "mlp"):
     d, ff = cfg.d_model, cfg.d_ff

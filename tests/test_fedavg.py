@@ -2,9 +2,10 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from repro import configs
-from repro.core import fedavg, selection
+from repro import configs, fl, obs
+from repro.core import factored, fedavg, selection
 from repro.core.fedavg import FLConfig
 from repro.data import femnist
 from repro.models import femnist_cnn
@@ -84,3 +85,122 @@ def test_overselection_backup():
     sel = selection.select_clients(rng, 100, 20, overselect=0.3)
     assert len(sel) == 26
     assert len(np.unique(sel)) == 26
+
+
+FC1 = 3136 * 2048       # the full CNN's dense weight, 6,422,528 params
+
+
+def _client_batches(n_clients, steps, batch, seed=3):
+    clients, _ = femnist.generate(
+        femnist.FemnistConfig(n_clients=n_clients, seed=seed))
+    rng = np.random.default_rng(0)
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *[
+        femnist.client_minibatches(rng, c, steps, batch) for c in clients])
+
+
+def _dense_sgd(params, batches, lr, loss=_loss):
+    """Plain H-step SGD, every weight a dense per-client copy."""
+    def step(p, b):
+        (l, _), g = jax.value_and_grad(loss, has_aux=True)(p, b)
+        return jax.tree.map(
+            lambda w, gw: (w.astype(jnp.float32) - lr * gw).astype(w.dtype),
+            p, g), l
+    p, losses = jax.lax.scan(step, params, batches)
+    return jax.tree.map(lambda a, b: a.astype(jnp.float32) - b, p, params), \
+        jnp.mean(losses)
+
+
+def test_factored_local_update_matches_dense_sgd():
+    """Full-width CNN, H=8, B=10: the update that carries ``fc1_w`` as the
+    global weight plus the step history follows plain dense SGD, and a
+    padding client (its minibatch mask all zero) moves nothing."""
+    params, _ = femnist_cnn.init_params(configs.get("femnist_cnn"),
+                                        jax.random.PRNGKey(0))
+    H, B, lr = 8, 10, 0.01
+    cb = _client_batches(3, H, B)
+    cb["mask"] = jnp.ones(cb["labels"].shape, jnp.float32).at[2].set(0.0)
+    flc = FLConfig(local_steps=H, local_batch=B, local_lr=lr, client_chunk=4)
+    with jax.default_matmul_precision("highest"):
+        program = fedavg.chunk_program(_loss, flc)
+        deltas, losses = program(params, cb)
+        ref, ref_losses = jax.vmap(_dense_sgd, in_axes=(None, 0, None))(
+            params, cb, lr)
+        p, _ = fedavg.local_sgd(params, jax.tree.map(lambda x: x[0], cb),
+                                _loss, lr, H)
+    assert program.factored_params == FC1
+    np.testing.assert_allclose(np.asarray(losses), np.asarray(ref_losses),
+                               rtol=1e-6, atol=1e-6)
+    for name in ref:
+        got, want = np.asarray(deltas[name]), np.asarray(ref[name])
+        gap = np.linalg.norm(got - want) / np.linalg.norm(want)
+        assert gap < 1e-4, (name, gap)
+        assert not np.any(got[2]), name
+    np.testing.assert_allclose(np.asarray(p["fc1_w"] - params["fc1_w"]),
+                               np.asarray(deltas["fc1_w"][0]), atol=1e-6)
+
+
+def _toy_loss(params, batch):
+    """A linear model that uses its weight as an array, not via dense."""
+    err = batch["x"] @ params["w"] - batch["y"]
+    return jnp.mean(err ** 2), {}
+
+
+def _eligibility_case(case):
+    """(loss, params, client batches, FLConfig, strategy) of one case."""
+    cnn = configs.get("femnist_cnn")
+    small = cnn.reduced()       # fc1_w 392 x 64: factored iff H*B < 55
+    H, B = {"cnn": (8, 10), "small_hb50": (5, 10), "small_hb80": (8, 10),
+            "small_bf16": (5, 10), "small_fedprox": (5, 10),
+            "toy": (2, 2)}[case]
+    flc = FLConfig(local_steps=H, local_batch=B, local_lr=0.01,
+                   client_chunk=2)
+    strategy = "fedprox" if case == "small_fedprox" else "sfl_two_step"
+    if case == "toy":
+        rng = np.random.default_rng(0)
+        params = {"w": jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)}
+        cb = {"x": jnp.asarray(rng.normal(size=(2, H, B, 64)), jnp.float32),
+              "y": jnp.asarray(rng.normal(size=(2, H, B, 32)), jnp.float32)}
+        return _toy_loss, params, cb, flc, strategy
+    params, _ = femnist_cnn.init_params(cnn if case == "cnn" else small,
+                                        jax.random.PRNGKey(0))
+    cb = _client_batches(2, H, B)
+    if case == "small_bf16":
+        params = jax.tree.map(lambda w: w.astype(jnp.bfloat16), params)
+        cb["images"] = cb["images"].astype(jnp.bfloat16)
+    return _loss, params, cb, flc, strategy
+
+
+@pytest.mark.parametrize("case,planned,n_params", [
+    ("cnn", ["fc1_w"], FC1),
+    ("small_hb50", ["fc1_w"], 392 * 64),
+    ("small_hb80", [], 0),              # 80 * (392 + 64) > 392 * 64
+    ("small_bf16", [], 0),
+    ("small_fedprox", ["fc1_w"], 0),    # local_sgd_prox stays dense
+    ("toy", [], 0),                     # the loss declares nothing
+])
+def test_factored_eligibility(case, planned, n_params):
+    """Which weights ``factored.plan`` picks, observed from the loss and
+    the shapes, and the ``train.chunk`` span's ``factored_params``: what
+    the client update that ran carries factored. Where FedAvg's update
+    picks nothing, its deltas are plain dense SGD's, bit for bit."""
+    from repro.obs.context import Obs
+    from repro.obs.tracer import Tracer
+
+    loss, params, cb, flc, strategy = _eligibility_case(case)
+    paths, treedef = jax.tree_util.tree_flatten_with_path(params)
+    one = jax.tree.map(lambda x: x[0], cb)
+    picked = factored.plan(loss, [w for _, w in paths], treedef, one)
+    assert [paths[i][0][0].key for i in picked] == planned
+    t = Tracer()
+    with obs.use(Obs(tracer=t)):
+        deltas, _ = fedavg.train_selected_clients(
+            params, cb, loss, flc,
+            local_update=fl.make_strategy(strategy).local_update)
+    spans = [s.args for s in t.spans if s.name == "train.chunk"]
+    assert [s["factored_params"] for s in spans] == [n_params]
+    if not planned:
+        ref, _ = jax.jit(jax.vmap(_dense_sgd, in_axes=(None, 0, None, None)),
+                         static_argnums=3)(params, cb, flc.local_lr, loss)
+        for name in ref:
+            np.testing.assert_array_equal(np.asarray(deltas[name]),
+                                          np.asarray(ref[name]), name)

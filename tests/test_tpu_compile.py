@@ -8,14 +8,16 @@ than HBM) it would refuse on the chip. Cases, at the paper's width:
   * the six ONU kernels over one ONU (C=20 clients × N=6,603,710 params,
     the full LEAF CNN), each lowering to a Mosaic ``tpu_custom_call``;
   * one ``ClientStackedBackend`` chunk step at full CNN width: the vmapped
-    local update over ``client_chunk=16`` clients, then the
-    ``sfl_two_step`` aggregate over 128 stacked rows.
+    local update over ``client_chunk=16`` clients, whose H-step loop holds
+    no per-client copy of the dense weight, then the ``sfl_two_step``
+    aggregate over 128 stacked rows.
 
 Nothing runs, so this says nothing about results or times. The topology is
 described inside a fixture (never at import): only one process at a time
 may load the TPU library, and each test worker imports every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -68,6 +70,28 @@ def _fits(compiled) -> int:
     return used
 
 
+def _while_bodies(text: str) -> str:
+    """The HLO text of every ``while`` body in a compiled module, with the
+    computations (fusions, reductions) they call."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    comps = {k: "\n".join(v) for k, v in comps.items()}
+    todo = [b for c in comps.values() for b in re.findall(r"body=%([\w.\-]+)", c)]
+    seen = set()
+    while todo:
+        n = todo.pop()
+        if n not in seen:
+            seen.add(n)
+            todo += [c for c in re.findall(r"%([\w.\-]+)", comps[n]) if c in comps]
+    return "\n".join(comps[n] for n in sorted(seen))
+
+
 def _kernel_case(name, sd):
     key = sd((2,), jnp.uint32)
     stack = (sd((C, N), jnp.float32), sd((C,), jnp.float32),
@@ -112,6 +136,11 @@ def test_client_stacked_chunk_step_compiles_for_v5e(one_chip):
                                    strategy.local_update)
     train = program.lower(place(params), batches).compile()
     _fits(train)
+    # the dense weight rides the H-step loop as the global weight plus the
+    # steps' rows: no per-client copy of it is carried or rewritten there
+    assert program.factored_params == 3136 * 2048
+    loops = _while_bodies(train.as_text())
+    assert loops and f"f32[{chunk},3136,2048]" not in loops
 
     rows = flc.n_selected
     deltas = place(jax.tree.map(
